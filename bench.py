@@ -213,7 +213,7 @@ def main() -> None:
     # BENCH_r*.json regression to capture/convert/dispatch/readback/
     # packetize instead of one opaque number -----------------------------
     from selkies_tpu.obs import qoe as _qoe
-    from selkies_tpu.trace import STAGES
+    from selkies_tpu.trace import FRAME_STAGES, STAGES
     from selkies_tpu.trace import tracer as _tracer
     from selkies_tpu.trace.summary import (occupancy_report,
                                            render_occupancy, render_table,
@@ -289,7 +289,8 @@ def main() -> None:
     n_traced = max(1, sum(1 for t in timelines if t.done))
     stages_ms = {s: round(stage_summary.get(s, {}).get("total_ms", 0.0)
                           / n_traced, 3) for s in STAGES}
-    stage_sum_ms = round(sum(stages_ms.values()), 3)
+    # the frame stages only: every other stage nests in one of them
+    stage_sum_ms = round(sum(stages_ms[s] for s in FRAME_STAGES), 3)
     log("per-stage breakdown (ms/frame, IDR latency loop):")
     log(render_table(stage_summary))
     log(f"stage_sum={stage_sum_ms:.2f}ms vs e2e_mean={lat_mean_ms:.2f}ms "

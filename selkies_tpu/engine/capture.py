@@ -460,9 +460,10 @@ class ScreenCapture:
                     _tracer.bind(tl, out["frame_id"])
                 if ring is not None:
                     # blocks while `depth` frames are in flight — the
-                    # engine's own backpressure; raises PipelineError
-                    # if a previous slot's finalize died
-                    ring.submit(out)
+                    # engine's own backpressure (traced as
+                    # pipeline.admit); raises PipelineError if a
+                    # previous slot's finalize died
+                    ring.submit(out, tl=tl)
                 else:
                     out["slot"] = 0
                     self._deliver(out)

@@ -274,11 +274,12 @@ class JpegEncoderSession:
         cap_gen = self._cap_gen
         if self._watermark is not None:
             frame = self._watermark.apply(frame)
-        # the dispatch span covers the step call AND the async-copy kicks:
-        # on TPU both are enqueue-cost only and the device compute lands
-        # in finalize's encode.readback stall, while backends whose copy
-        # kick synchronizes (CPU) show the compute here — either way the
-        # host-visible wait is attributed, never lost between spans
+        # the dispatch span covers the step call AND the async-copy kicks,
+        # which on TPU only enqueue (about a millisecond a 1080p frame on
+        # a v5e); the host waits for the step's compute in finalize's
+        # readback.device_wait, and a stripe fetch's slice may queue
+        # behind the NEXT frame's step (readback.slice_wait). Backends
+        # whose copy kick synchronizes (CPU) show the compute here
         with _tracer.span("encode.dispatch"):
             data, lens, send, is_paint, age, prev_out, overflow = \
                 self._step(frame, self._prev, self._age,
@@ -322,20 +323,19 @@ class JpegEncoderSession:
         g = self.grid
         # trace target: THIS frame's timeline, by id — never the current
         # dispatch context, which is up to pipeline_depth frames ahead.
-        # ONE readback span per frame on this (batch) path: overflow
-        # sync (absorbs the step's compute stall) + the stream fetch;
-        # the streaming path (finalize_stream) intentionally fragments
-        # per stripe instead — totals stay identical either way.
+        # ONE readback span per frame on this (batch) path, from the
+        # slot's SUBMIT instant (in-flight time is readback time, not
+        # bubble; serial calls start here): the ring's admission and
+        # queue, the control sync (readback.device_wait) and the stream
+        # fetch (readback.copy). The streaming path (finalize_stream)
+        # adds a span per stripe fetch instead — totals stay identical.
         tl = _tracer.lookup(self.settings.display_id, out["frame_id"])
         # per-slot lane (deep pipeline): occupancy attribution must see
         # WHICH in-flight slot ran, not just "the finalizer thread"
         lane = f"slot{out['slot']}" if "slot" in out else None
-        # readback span epoch: a pipelined slot's time-to-bytes starts
-        # at its SUBMIT instant (in-flight time is readback time, not
-        # bubble); serial calls start here
-        rb_t0 = out.get("submitted_ns") or time.perf_counter_ns()
+        rb_t0 = out.get("submitted_ns") or time.monotonic_ns()
         overflowed, idle, force_all, lens, send, is_paint = \
-            self._sync_control(out, force_all)
+            self._sync_control(out, force_all, tl, lane)
         data = None
         if not overflowed and not idle:
             starts = np.concatenate([[0], np.cumsum(lens)])
@@ -347,8 +347,10 @@ class JpegEncoderSession:
             deliver = np.nonzero(send)[0] if not force_all \
                 else np.arange(g.n_stripes)
             last = int(deliver[-1])
-            data = fetch_stream_bytes(out["data"],
-                                      int(starts[last] + lens[last]))
+            with _tracer.span("readback.copy", tl, lane=lane):
+                data = fetch_stream_bytes(out["data"],
+                                          int(starts[last] + lens[last]),
+                                          tl=tl, lane=lane)
         _tracer.record_span(tl, "encode.readback", rb_t0, lane=lane)
         if overflowed:
             self._handle_overflow(out)
@@ -375,9 +377,9 @@ class JpegEncoderSession:
         g = self.grid
         tl = _tracer.lookup(self.settings.display_id, out["frame_id"])
         lane = f"slot{out['slot']}" if "slot" in out else None
-        rb_t0 = out.get("submitted_ns") or time.perf_counter_ns()
+        rb_t0 = out.get("submitted_ns") or time.monotonic_ns()
         overflowed, idle, force_all, lens, send, is_paint = \
-            self._sync_control(out, force_all)
+            self._sync_control(out, force_all, tl, lane)
         _tracer.record_span(tl, "encode.readback", rb_t0, lane=lane)
         if overflowed:
             self._handle_overflow(out)
@@ -389,26 +391,33 @@ class JpegEncoderSession:
         for i in range(g.n_stripes):
             if not (force_all or send[i]):
                 continue
-            with _tracer.span("encode.readback", tl, lane=lane):
+            with _tracer.span("encode.readback", tl, lane=lane), \
+                    _tracer.span("readback.copy", tl, lane=lane):
                 raw = fetch_stripe_bytes(out["data"], int(starts[i]),
-                                         int(lens[i]))
+                                         int(lens[i]), tl=tl, lane=lane)
             with _tracer.span("packetize", tl, lane=lane):
                 chunk = self._chunk(out, i, raw, bool(is_paint[i]))
             yield chunk
 
-    def _sync_control(self, out: dict[str, Any], force_all: bool):
+    def _sync_control(self, out: dict[str, Any], force_all: bool, tl=None,
+                      lane=None):
         """Control-array sync shared by finalize and finalize_stream —
-        the one device-sync point (absorbs the step's compute stall) and
-        the force-after-drop promotion. -> (overflowed, idle, force_all,
+        the one device-sync point (absorbs the step's compute stall;
+        ``readback.device_wait`` on the frame's timeline ``tl``) and the
+        force-after-drop promotion. -> (overflowed, idle, force_all,
         lens, send, is_paint)."""
-        if bool(np.asarray(out["overflow"])):
+        with _tracer.span("readback.device_wait", tl, lane=lane,
+                          wait=True):
+            overflow = bool(np.asarray(out["overflow"]))
+            if not overflow:
+                lens = np.asarray(out["lens"])
+                send = np.asarray(out["send"])
+                is_paint = np.asarray(out["is_paint"])
+        if overflow:
             return True, True, force_all, None, None, None
         if self._force_after_drop:
             self._force_after_drop = False
             force_all = True
-        lens = np.asarray(out["lens"])
-        send = np.asarray(out["send"])
-        is_paint = np.asarray(out["is_paint"])
         idle = not (force_all or send.any())
         return False, idle, force_all, lens, send, is_paint
 

@@ -526,11 +526,10 @@ class H264EncoderSession:
             # dispatch (or no dispatch at all on an idle frame)
             with _tracer.span("encode.dispatch"):
                 return self._dispatch_partial(frame, intra, cap_gen)
-        # the dispatch span covers the step call AND the async-copy kicks:
-        # on TPU both are enqueue-cost only and the device compute lands
-        # in finalize's encode.readback stall, while backends whose copy
-        # kick synchronizes (CPU) show the compute here — either way the
-        # host-visible wait is attributed, never lost between spans
+        # the dispatch span covers the step call AND the async-copy kicks,
+        # which on TPU only enqueue; the host waits for the step's
+        # compute later, in finalize's readback.device_wait (backends
+        # whose copy kick synchronizes, CPU, show the compute here)
         with _tracer.span("encode.dispatch"):
             return self._dispatch_stock(frame, intra, cap_gen)
 
@@ -580,11 +579,16 @@ class H264EncoderSession:
         g, s = self.grid, self.settings
         rps = g.rows_per_stripe
         probe = _jitted_row_damage_probe(g.width, g.height)
-        # the one host sync of the partial path — (R,) bools. It also
-        # closes the dispatch-overlap window a full-frame pipeline would
-        # have had; PERF.md lever 5 documents why the trade wins for
-        # desktop content (most frames become cheap or free).
-        dirty_rows = np.asarray(probe(frame, self._prev))
+        tl = _tracer.current()
+        # the one host sync of the partial path — (R,) bools. The probe
+        # reads the previous step's reference, so on a chip this sync
+        # waits for that band step to finish: the dispatch-overlap window
+        # a full-frame pipeline would have had is closed here (traced as
+        # encode.probe_sync); PERF.md lever 5 documents why the trade wins
+        # for desktop content (most frames become cheap or free).
+        probed = probe(frame, self._prev)
+        with _tracer.span("encode.probe_sync", tl, wait=True):
+            dirty_rows = np.asarray(probed)
         stripe_dirty = dirty_rows.reshape(g.n_stripes, rps).any(axis=1)
         self.dirty_fraction = _dirty_fraction(dirty_rows)
         age_pre = self._host_age
@@ -673,16 +677,18 @@ class H264EncoderSession:
         decision for this codec (idr parity lives on device)."""
         del force_all
         g = self.grid
-        # ONE readback span per frame: the overflow flag is the
-        # device-sync point and the stream fetch the link cost — two
-        # fragments would double the stage count and skew percentiles
+        # ONE readback span per frame, from the slot's submit (in-flight
+        # time IS readback time) through the ring's admission and queue
+        # (pipeline.admit, pipeline.queue), the control sync
+        # (readback.device_wait) and the stream fetch (readback.copy) —
+        # two fragments would double the stage count and skew percentiles
         tl = _tracer.lookup(self.settings.display_id, out["frame_id"])
         # per-slot lane (deep pipeline): occupancy attribution must see
         # WHICH in-flight slot ran, not just "the finalizer thread"
         lane = f"slot{out['slot']}" if "slot" in out else None
-        # readback epoch: a pipelined slot's in-flight time IS readback
-        rb_t0 = out.get("submitted_ns") or time.perf_counter_ns()
-        overflowed, idle, lens, send, intra = self._sync_control(out)
+        rb_t0 = out.get("submitted_ns") or time.monotonic_ns()
+        overflowed, idle, lens, send, intra = self._sync_control(out, tl,
+                                                                 lane)
         band = out.get("band")
         data = starts = None
         if not overflowed and not idle:
@@ -699,8 +705,10 @@ class H264EncoderSession:
             else:
                 last_row = self._band_last_row(send, band)
             if last_row is not None:
-                data = fetch_stream_bytes(
-                    out["data"], int(starts[last_row] + lens[last_row]))
+                with _tracer.span("readback.copy", tl, lane=lane):
+                    data = fetch_stream_bytes(
+                        out["data"], int(starts[last_row] + lens[last_row]),
+                        tl=tl, lane=lane)
         _tracer.record_span(tl, "encode.readback", rb_t0, lane=lane)
         if overflowed:
             self._handle_overflow(out)
@@ -763,8 +771,9 @@ class H264EncoderSession:
         g = self.grid
         tl = _tracer.lookup(self.settings.display_id, out["frame_id"])
         lane = f"slot{out['slot']}" if "slot" in out else None
-        rb_t0 = out.get("submitted_ns") or time.perf_counter_ns()
-        overflowed, idle, lens, send, intra = self._sync_control(out)
+        rb_t0 = out.get("submitted_ns") or time.monotonic_ns()
+        overflowed, idle, lens, send, intra = self._sync_control(out, tl,
+                                                                 lane)
         _tracer.record_span(tl, "encode.readback", rb_t0, lane=lane)
         if overflowed:
             self._handle_overflow(out)
@@ -782,9 +791,11 @@ class H264EncoderSession:
             data = None
             if lb is not None:
                 from .readback import fetch_stream_bytes
-                with _tracer.span("encode.readback", tl, lane=lane):
+                with _tracer.span("encode.readback", tl, lane=lane), \
+                        _tracer.span("readback.copy", tl, lane=lane):
                     data = fetch_stream_bytes(
-                        out["data"], int(starts[lb] + lens[lb]))
+                        out["data"], int(starts[lb] + lens[lb]),
+                        tl=tl, lane=lane)
             for i in range(g.n_stripes):
                 if not send[i]:
                     continue
@@ -799,10 +810,12 @@ class H264EncoderSession:
             if not send[i]:
                 continue
             r0, r1 = i * rps, (i + 1) * rps
-            with _tracer.span("encode.readback", tl, lane=lane):
+            with _tracer.span("encode.readback", tl, lane=lane), \
+                    _tracer.span("readback.copy", tl, lane=lane):
                 raw = fetch_stripe_bytes(
                     out["data"], int(starts[r0]),
-                    int(starts[r1 - 1] + lens[r1 - 1] - starts[r0]))
+                    int(starts[r1 - 1] + lens[r1 - 1] - starts[r0]),
+                    tl=tl, lane=lane)
             with _tracer.span("packetize", tl, lane=lane):
                 base = int(starts[r0])
                 rows = [bytes(raw[starts[r] - base:
@@ -819,17 +832,22 @@ class H264EncoderSession:
         del out
         return np.concatenate([[0], np.cumsum(lens[:-1])]).astype(np.int64)
 
-    def _sync_control(self, out: dict[str, Any]):
+    def _sync_control(self, out: dict[str, Any], tl=None, lane=None):
         """Control-array sync shared by finalize and finalize_stream —
-        the one device-sync point. -> (overflowed, idle, lens, send,
+        the one device-sync point (``readback.device_wait`` on the
+        frame's timeline ``tl``). -> (overflowed, idle, lens, send,
         intra)."""
         if out.get("idle"):
             # partial-path idle frame: nothing was dispatched at all
             return False, True, None, None, False
-        if bool(np.asarray(out["overflow"])):
+        with _tracer.span("readback.device_wait", tl, lane=lane,
+                          wait=True):
+            overflow = bool(np.asarray(out["overflow"]))
+            if not overflow:
+                lens = np.asarray(out["lens"])    # (R,) per MB row
+                send = np.asarray(out["send"])
+        if overflow:
             return True, True, None, None, True
-        lens = np.asarray(out["lens"])    # (R,) per MB row
-        send = np.asarray(out["send"])
         intra = out.get("intra", True)
         idle = not send.any()
         return False, idle, lens, send, intra

@@ -29,6 +29,10 @@ Invariants the ring enforces:
   slot index (``out["slot"]``); the encoder sessions label their
   readback/packetize spans with a ``slotN`` lane so the occupancy
   analyzer (obs.perf / trace.summary) attributes overlap exactly.
+- **Its waits are traced.** Given the frame's timeline, ``submit``
+  records the time it blocked as ``pipeline.admit`` (the submitting
+  thread's wait lane) and the slot's wait for the finalizer as
+  ``pipeline.queue`` (``slotN.wait``).
 
 Stdlib-only: the ring is plain threading, importable without jax.
 """
@@ -40,6 +44,8 @@ import logging
 import threading
 import time
 from typing import Callable, Optional
+
+from ..trace import tracer as _tracer
 
 logger = logging.getLogger("selkies_tpu.engine.pipeline")
 
@@ -146,16 +152,18 @@ class PipelineRing:
             self._depth = max(1, int(depth))
             self._cond.notify_all()
 
-    def submit(self, out: dict) -> int:
+    def submit(self, out: dict, tl=None) -> int:
         """Enqueue one dispatched slot; blocks while ``depth`` slots are
         in flight (the capture thread's backpressure). Returns the slot
         index stamped into ``out["slot"]``. Raises :class:`PipelineError`
-        if a previous slot's finalize failed."""
+        if a previous slot's finalize failed. ``tl`` is the frame's
+        trace timeline (None: not traced)."""
         # in-flight epoch BEFORE the admission wait: the frame was
         # already dispatched when submit() was called, so time spent
         # blocked here is genuine in-flight time — the encoder's
         # readback span starts at this instant
-        t_submit = time.perf_counter_ns()
+        t_submit = time.monotonic_ns()
+        traced = tl is not None and _tracer.enabled
         with self._cond:
             while (self._in_flight >= self._depth and self._failure is None
                    and not self._closed):
@@ -166,11 +174,15 @@ class PipelineRing:
             slot = self._seq % self._depth
             out["slot"] = slot
             out["submitted_ns"] = t_submit
+            t_admit = time.monotonic_ns() if traced else 0
             self._seq += 1
             self._in_flight += 1
-            self._q.append(out)
+            self._q.append((out, tl if traced else None, t_admit))
             self._cond.notify_all()
-            return slot
+        if traced:
+            _tracer.record_span(tl, "pipeline.admit", t_submit,
+                                end_ns=t_admit, wait=True)
+        return slot
 
     def drain(self, timeout: Optional[float] = None) -> bool:
         """Block until every in-flight slot delivered (the stop path's
@@ -221,7 +233,10 @@ class PipelineRing:
                     if self._closed:
                         return
                     continue
-                out = self._q.popleft()
+                out, tl, t_admit = self._q.popleft()
+            if tl is not None:
+                _tracer.record_span(tl, "pipeline.queue", t_admit,
+                                    lane=f"slot{out['slot']}", wait=True)
             try:
                 self._finalize(out)
             except BaseException as e:  # noqa: BLE001 — must not wedge

@@ -22,6 +22,14 @@ not bandwidth-bound.
 Both fetch paths carry the ``readback.fetch`` fault point
 (``slow``/``error``): an injected mid-pipeline readback death exercises
 the ring-drain recovery path (``bench.py --chaos``).
+
+Given the frame's trace timeline (keyword ``tl``, tracer on), a fetch
+counts ``readback.bytes`` (what the transfer moves: the bucket, or the
+whole buffer), and waits for the bucketed slice on its own before the
+transfer completes (``readback.slice_wait`` on ``<lane>.wait``): the
+slice is enqueued on the device stream and can sit behind the next
+frame's step.
+With the tracer off nothing is synced that the transfer would not sync.
 """
 
 from __future__ import annotations
@@ -31,6 +39,7 @@ import functools
 import numpy as np
 
 from ..resilience import faults as _faults
+from ..trace import tracer as _tracer
 
 #: smallest whole-frame fetch; below this the dispatch RTT dominates
 MIN_BUCKET = 32768
@@ -83,27 +92,53 @@ def bucket_for(total: int, floor: int = MIN_BUCKET) -> int:
     return b
 
 
-def fetch_stream_bytes(data_dev, total: int) -> np.ndarray:
+def _transfer(arr, tl, lane, extent: int | None = None,
+              sync: bool = True) -> np.ndarray:
+    """``np.asarray(arr)``: the device-to-host copy. With ``tl`` traced:
+    counted (``extent``, the last-axis length that crosses, default all
+    of it, per leading index) and, for a device slice (``sync``), its
+    wait for the device apart from the copy."""
+    if tl is None or not _tracer.enabled:
+        return np.asarray(arr)
+    width = int(arr.shape[-1])
+    rows = int(arr.size) // width if width else 0
+    _tracer.count(tl, "readback.bytes",
+                  rows * (width if extent is None else extent))
+    if sync:
+        # the copy is queued behind the slice first, as np.asarray alone
+        # would: the wait below then adds no host round trip to it
+        arr.copy_to_host_async()
+        with _tracer.span("readback.slice_wait", tl, lane=lane, wait=True):
+            arr.block_until_ready()
+    return np.asarray(arr)
+
+
+def fetch_stream_bytes(data_dev, total: int, *, tl=None,
+                       lane=None) -> np.ndarray:
     """Fetch the first ``total`` bytes (along the last axis) of the
     device stream buffer, rounded up to a bucket so the jit cache stays
-    tiny."""
+    tiny. ``tl``/``lane``: the frame's trace timeline and the span lane
+    (module docstring)."""
     _faults.registry.perturb("readback.fetch")
     if total <= 0:
         return np.zeros(tuple(data_dev.shape[:-1]) + (0,), np.uint8)
     n = int(data_dev.shape[-1])
     if _on_host(data_dev):
-        return np.asarray(data_dev)[..., :min(total, n)]
+        k = min(total, n)
+        return _transfer(data_dev, tl, lane, k, sync=False)[..., :k]
     bucket = bucket_for(total)
     if bucket >= n:
-        return np.asarray(data_dev)
-    return np.asarray(_slice_fn(bucket)(data_dev))
+        return _transfer(data_dev, tl, lane)
+    return _transfer(_slice_fn(bucket)(data_dev), tl, lane)
 
 
-def fetch_stripe_bytes(data_dev, start: int, length: int) -> np.ndarray:
+def fetch_stripe_bytes(data_dev, start: int, length: int, *, tl=None,
+                       lane=None) -> np.ndarray:
     """Fetch ``length`` bytes at ``start`` (along the last axis) — the
     stripe-streaming fetch. Byte-identical to the same range of a
     whole-prefix fetch; the bucketed device slice may over-fetch up to
-    one bucket, never under."""
+    one bucket, never under. ``tl``/``lane`` as for
+    :func:`fetch_stream_bytes`."""
     _faults.registry.perturb("readback.fetch")
     if length <= 0:
         return np.zeros(tuple(data_dev.shape[:-1]) + (0,), np.uint8)
@@ -111,13 +146,14 @@ def fetch_stripe_bytes(data_dev, start: int, length: int) -> np.ndarray:
     start = max(0, int(start))
     length = min(int(length), n - start)
     if _on_host(data_dev):
-        return np.asarray(data_dev)[..., start:start + length]
+        return _transfer(data_dev, tl, lane, length,
+                         sync=False)[..., start:start + length]
     bucket = bucket_for(length, MIN_STRIPE_BUCKET)
     if bucket >= n:
-        return np.asarray(data_dev)[..., start:start + length]
+        return _transfer(data_dev, tl, lane)[..., start:start + length]
     # dynamic_slice clamps start to n - bucket: fetch the clamped
     # window and re-offset on the host so the caller's range is exact
     eff = min(start, n - bucket)
-    raw = np.asarray(_stripe_slice_fn(bucket)(data_dev, eff))
+    raw = _transfer(_stripe_slice_fn(bucket)(data_dev, eff), tl, lane)
     off = start - eff
     return raw[..., off:off + length]

@@ -9,7 +9,13 @@ state machine so the HTTP plane (``POST /api/profile``) and
 - start/stop both return structured status dicts instead of raising —
   the API endpoint maps them straight to JSON;
 - the capture directory defaults to a fresh ``selkies-profile-*``
-  tempdir so an operator can hit the endpoint with an empty body.
+  tempdir so an operator can hit the endpoint with an empty body;
+- each capture opens with a clock mark: a ``selkies.clock`` trace
+  annotation carrying the host's ``CLOCK_MONOTONIC`` ns (``host_ns``, also
+  kept as ``clock_mark_ns`` and served in ``GET /api/trace``'s
+  ``otherData``), so the span tracer's timelines, which read that clock,
+  can be placed on the capture's device lanes: host ns minus the mark's
+  trace timestamp is the offset.
 
 Both entry points do real file I/O inside jax (``stop_trace`` serialises
 the whole capture): callers on an event loop must run them in an
@@ -26,6 +32,9 @@ from typing import Optional
 
 logger = logging.getLogger("selkies_tpu.obs.profiler")
 
+#: the trace annotation that ties the capture's clock to the host's
+CLOCK_MARK = "selkies.clock"
+
 
 class ProfilerSession:
     """Process-wide jax.profiler capture guard."""
@@ -41,6 +50,8 @@ class ProfilerSession:
         #: a jax start/stop call is in flight (outside the lock); a new
         #: start must not race a still-serialising stop
         self._busy = False
+        #: host CLOCK_MONOTONIC ns of the last capture's clock mark
+        self.clock_mark_ns: Optional[int] = None
 
     @property
     def active(self) -> bool:
@@ -66,6 +77,9 @@ class ProfilerSession:
         try:
             import jax
             jax.profiler.start_trace(target)
+            mark = time.monotonic_ns()
+            with jax.profiler.TraceAnnotation(CLOCK_MARK, host_ns=mark):
+                pass
         except Exception as e:
             with self._lock:
                 self.trace_dir = None
@@ -76,6 +90,7 @@ class ProfilerSession:
                     "error": f"{type(e).__name__}: {e}"}
         with self._lock:
             self._busy = False
+            self.clock_mark_ns = mark
         logger.info("jax profiler capture started -> %s", target)
         return {"ok": True, "active": True, "trace_dir": target}
 

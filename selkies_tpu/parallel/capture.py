@@ -210,7 +210,7 @@ class MultiSeatCapture:
                     _tracer.bind(tl, out["frame_id"],
                                  aliases=seat_aliases)
                 if ring is not None:
-                    ring.submit(out)
+                    ring.submit(out, tl=tl)
                 else:
                     out["slot"] = 0
                     self._deliver(out)

@@ -189,7 +189,7 @@ class MultiSeatH264Encoder:
         del force_all                       # encode()-time decision
         g = self.grid
         tl = _tracer.lookup(self.settings.display_id, out["frame_id"])
-        rb_t0 = out.get("submitted_ns") or time.perf_counter_ns()
+        rb_t0 = out.get("submitted_ns") or time.monotonic_ns()
         lens = np.asarray(out["lens"])      # (S, R)
         send = np.asarray(out["send"])      # (S, n_stripes)
         overflow = np.asarray(out["overflow"])   # (S,)

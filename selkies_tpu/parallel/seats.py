@@ -190,7 +190,7 @@ class MultiSeatEncoder:
         # fetch); fragments would double the stage count. Epoch: a
         # pipelined slot's in-flight time (submit -> bytes) IS readback.
         tl = _tracer.lookup(self.settings.display_id, out["frame_id"])
-        rb_t0 = out.get("submitted_ns") or time.perf_counter_ns()
+        rb_t0 = out.get("submitted_ns") or time.monotonic_ns()
         lens = np.asarray(out["lens"])        # (S, n_stripes)
         send = np.asarray(out["send"])
         is_paint = np.asarray(out["is_paint"])

@@ -357,8 +357,10 @@ class CentralizedStreamServer:
         from ..trace.summary import summarize_timelines
         if not tracer.enabled:
             return _health.ok("tracing disabled (enable via /api/trace)")
+        # work stages only: a queue's wait (a *.wait lane) is not a stage
+        # running over budget, and must not flip /api/health
         summary = summarize_timelines(
-            t for t in tracer.snapshot() if t.done)
+            (t for t in tracer.snapshot() if t.done), waits=False)
         if not summary:
             return _health.ok("tracing on, no completed frames yet")
         budget = float(getattr(self.settings, "health_stage_budget_ms",
@@ -796,7 +798,8 @@ class CentralizedStreamServer:
         doc = to_trace_events(snap, process_name=self.settings.app_name)
         # device-lane overlay: XLA compile events from jax.monitoring,
         # so a Perfetto view shows "recompile happened HERE" against the
-        # frame timeline (same perf_counter timebase)
+        # frame timeline (the overlays stamp perf_counter, which CPython
+        # backs with the tracer's CLOCK_MONOTONIC on Linux)
         doc["traceEvents"].extend(monitor.trace_events())
         # qoe-lane overlay: backpressure windows against the frame
         # timeline, so a Perfetto view shows WHEN a seat was paused
@@ -807,6 +810,10 @@ class CentralizedStreamServer:
             doc["traceEvents"].extend(self.ladder.trace_events())
         doc["otherData"] = tracer.stats(frames=len(snap))
         doc["otherData"]["compile"] = monitor.compile_stats()
+        # the last /api/profile capture's clock mark (host ns): places
+        # these spans on that capture's device lanes
+        from ..obs.profiler import profiler
+        doc["otherData"]["clock_mark_ns"] = profiler.clock_mark_ns
         return web.json_response(doc)
 
     async def handle_trace_control(self, request: web.Request) -> web.Response:

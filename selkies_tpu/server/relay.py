@@ -31,6 +31,7 @@ from ..protocol import (FRAME_TYPE_IDR, OP_H264, OP_JPEG,
                         unpack_h264_header, unpack_jpeg_header)
 from ..resilience import faults as _faults
 from ..trace import tracer as _tracer
+from ..trace.core import now_ns as _trace_now_ns
 from . import metrics
 
 logger = logging.getLogger("selkies_tpu.server.relay")
@@ -92,6 +93,9 @@ class VideoRelay:
         self._counted_alive = False
         self._q: deque[bytes] = deque()
         self._q_bytes = 0
+        #: id(item) -> when it was queued, kept while tracing: an item's
+        #: time in the queue is its relay.queue span
+        self._queued_at: dict[int, int] = {}
         self._wake = asyncio.Event()
         self._task: Optional[asyncio.Task] = None
         self.dead = False
@@ -143,6 +147,8 @@ class VideoRelay:
                 return
         self._q.append(item)
         self._q_bytes += len(item)
+        if _tracer.enabled and self.display is not None:
+            self._queued_at[id(item)] = _trace_now_ns()
         # fault point relay.stripe:reorder — swap the two newest queued
         # stripes so the wire delivers them out of order (stripe
         # streaming makes per-stripe sends the common case; the decode
@@ -160,6 +166,8 @@ class VideoRelay:
         while self._q_bytes > self.budget and len(self._q) > 1:
             victim = self._q.popleft()
             self._q_bytes -= len(victim)
+            if self._queued_at:
+                self._queued_at.pop(id(victim), None)
             self.dropped_frames += 1
             metrics.inc_counter("selkies_relay_dropped_frames_total",
                                 labels={"display": self.display or "?"})
@@ -185,17 +193,25 @@ class VideoRelay:
                     continue
                 item = self._q.popleft()
                 self._q_bytes -= len(item)
+                t_in = self._queued_at.pop(id(item), None) \
+                    if self._queued_at else None
                 traced = _tracer.enabled and self.display is not None
                 try:
-                    t0 = time.perf_counter_ns() if traced else 0
+                    t0 = _trace_now_ns() if traced else 0
                     await asyncio.wait_for(self._guarded_send(item),
                                            self.send_timeout_s)
                     if traced:
                         fid = _wire_frame_id(item)
                         if fid is not None:
+                            # ws.send ends before the queue's span is
+                            # recorded, so it holds the send alone
                             _tracer.attach_span(
                                 self.display, fid, "ws.send", t0,
-                                time.perf_counter_ns() - t0, lane="ws")
+                                _trace_now_ns() - t0, lane="ws")
+                            if t_in is not None:
+                                _tracer.attach_span(
+                                    self.display, fid, "relay.queue", t_in,
+                                    t0 - t_in, lane="ws.wait")
                     self.sent_bytes += len(item)
                     metrics.inc_counter("selkies_relay_sent_bytes_total",
                                         len(item),
@@ -229,6 +245,7 @@ class VideoRelay:
         self.dead = True
         self._q.clear()
         self._q_bytes = 0
+        self._queued_at.clear()
         metrics.inc_counter("selkies_relay_deaths_total")
         _health.engine.recorder.record(
             "relay_death", display=self.display,

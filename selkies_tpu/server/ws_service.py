@@ -42,6 +42,7 @@ from ..resilience import faults as _faults
 from ..settings import AppSettings, SettingsError
 from ..taskutil import spawn_retained
 from ..trace import tracer as _tracer
+from ..trace.core import now_ns as _trace_now_ns
 from . import metrics
 from .core import BaseStreamingService
 from .relay import VideoRelay
@@ -857,8 +858,13 @@ class WebSocketsService(BaseStreamingService):
 
                 def cb(chunk: EncodedChunk) -> None:
                     # thread -> loop boundary: the ONLY entry point
-                    # (reference selkies.py:4294)
-                    loop.call_soon_threadsafe(self._do_fanout, chunk)
+                    # (reference selkies.py:4294); traced, the hop's
+                    # wait for the loop is fanout.hop
+                    if _tracer.enabled:
+                        loop.call_soon_threadsafe(self._fanout_hopped,
+                                                  chunk, _trace_now_ns())
+                    else:
+                        loop.call_soon_threadsafe(self._do_fanout, chunk)
 
                 def cursor_cb(cur: dict) -> None:
                     loop.call_soon_threadsafe(self._on_cursor, cur)
@@ -972,6 +978,14 @@ class WebSocketsService(BaseStreamingService):
         self._spawn_retained(self._broadcast_control(self._last_cursor_msg))
 
     # ---------------------------------------------------------------- fanout
+    def _fanout_hopped(self, chunk: EncodedChunk, t_hop: int) -> None:
+        """Traced entry from the finalizer: the chunk's wait between
+        ``call_soon_threadsafe`` (``t_hop``) and this loop turn is its
+        ``fanout.hop``, then the fan-out itself."""
+        _tracer.record_span(_tracer.lookup(chunk.display_id, chunk.frame_id),
+                            "fanout.hop", t_hop, lane="loop", wait=True)
+        self._do_fanout(chunk)
+
     def _do_fanout(self, chunk: EncodedChunk) -> None:
         """Runs on the loop; wire-frames once, offers to every relay.
         Synchronous — no awaits (reference selkies.py:4234-4292)."""

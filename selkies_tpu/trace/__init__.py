@@ -20,14 +20,29 @@ carries endpoint-level gauges. This package is the attribution layer:
 Everything here is stdlib-only: the CLI and exporter must run in images
 with neither jax nor aiohttp installed (the CI lint job).
 
-Stage names used across the repo (the bench breakdown contract):
-``capture``, ``convert``, ``encode.dispatch``, ``encode.readback``,
-``packetize``, ``fanout``, ``ws.send`` — plus the ``ack`` instant.
+Stage names used across the repo (the bench breakdown contract): the
+frame stages ``capture``, ``convert``, ``encode.dispatch``,
+``encode.readback``, ``packetize``, ``fanout``, ``ws.send``, the work
+span ``readback.copy`` nested in ``encode.readback``, the wait spans
+(on ``<lane>.wait``) ``encode.probe_sync``, ``pipeline.admit``,
+``pipeline.queue``, ``readback.device_wait``, ``readback.slice_wait``,
+``fanout.hop`` and ``relay.queue`` — plus the ``ack`` instant. Counter:
+``readback.bytes``.
 """
 
 from .core import FrameTracer, FrameTimeline, tracer  # noqa: F401
 
+#: the stages a frame passes through one after another: no two nest, so
+#: their sum is the frame's attributed time
+FRAME_STAGES = ("capture", "convert", "encode.dispatch", "encode.readback",
+                "packetize", "fanout", "ws.send")
+
+#: the spans that only wait, each on the wait lane of where it blocks
+WAIT_STAGES = ("encode.probe_sync", "pipeline.admit", "pipeline.queue",
+               "readback.device_wait", "readback.slice_wait", "fanout.hop",
+               "relay.queue")
+
 #: the repo-wide stage-name contract (bench reports every one of these,
-#: zero-filled when a stage cannot occur in its loop, e.g. ws.send)
-STAGES = ("capture", "convert", "encode.dispatch", "encode.readback",
-          "packetize", "fanout", "ws.send")
+#: zero-filled when a stage cannot occur in its loop, e.g. ws.send);
+#: every name but the frame stages nests in one of them or lies between
+STAGES = FRAME_STAGES + ("readback.copy",) + WAIT_STAGES

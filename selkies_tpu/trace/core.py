@@ -17,8 +17,18 @@ Design constraints (ISSUE 2 tentpole):
   last). Spans recorded outside the dispatch context attach by
   ``(display_id, frame_id)`` through a bounded index that shares the
   ring's eviction.
-- **Monotonic clock.** ``time.perf_counter_ns`` everywhere; wall-clock
-  never enters a duration.
+- **One clock.** ``time.monotonic_ns`` (``CLOCK_MONOTONIC``) everywhere,
+  the clock the profiler's clock mark (``obs/profiler``) and the
+  benchmark read, so spans can be placed on a device trace; wall-clock
+  never enters a duration. Code that stamps an instant for a span
+  recorded later uses :func:`now_ns`.
+- **Waits apart from work.** A span that only waits (a device sync, a
+  queue, an admission) is recorded with ``wait=True`` on the lane
+  ``<lane>.wait``, its own track under the thread; a work span carries
+  the thread CPU time it used (``cpu_ns``) while the tracer is on, so
+  wall minus waits minus CPU is time the thread was not running.
+- **Counters.** ``count(tl, name, n)`` adds to the frame's ``counts``
+  (the bytes a readback moved).
 """
 
 from __future__ import annotations
@@ -29,12 +39,26 @@ import time
 from collections import OrderedDict
 from typing import Callable, Optional
 
-__all__ = ["FrameTimeline", "FrameTracer", "tracer"]
+__all__ = ["FrameTimeline", "FrameTracer", "now_ns", "tracer", "wait_lane"]
 
 #: completed frame timelines kept for export (per process, across displays)
 DEFAULT_CAPACITY = 512
 
-_now_ns = time.perf_counter_ns
+_now_ns = time.monotonic_ns
+_cpu_ns = time.thread_time_ns
+
+#: suffix of the lane a wait span goes on: ``<lane>.wait``
+WAIT_SUFFIX = ".wait"
+
+
+def now_ns() -> int:
+    """The tracer's clock, for stamps that become spans later."""
+    return _now_ns()
+
+
+def wait_lane(lane: Optional[str] = None) -> str:
+    """The wait lane of ``lane`` (default: the current thread's)."""
+    return (lane or threading.current_thread().name) + WAIT_SUFFIX
 
 #: the timeline the current thread/task is dispatching (set by frame_begin)
 _current: contextvars.ContextVar[Optional["FrameTimeline"]] = \
@@ -42,18 +66,23 @@ _current: contextvars.ContextVar[Optional["FrameTimeline"]] = \
 
 
 class FrameTimeline:
-    """One frame's spans. ``spans`` holds ``(name, lane, t0_ns, dur_ns)``
-    tuples; ``lane`` maps to a Perfetto track (thread name, ``seatN``,
-    ``clientN``…). Mutated via the tracer only."""
+    """One frame's spans and counters. ``spans`` holds ``(name, lane,
+    t0_ns, dur_ns, cpu_ns)`` tuples; ``lane`` maps to a Perfetto track
+    (thread name, ``seatN``, ``clientN``, ``<lane>.wait``…); ``cpu_ns``
+    is the thread CPU time of a work span, None for waits and spans
+    measured from stamps. ``counts`` maps a counter's name to its sum.
+    Mutated via the tracer only."""
 
-    __slots__ = ("display_id", "frame_id", "t0_ns", "t1_ns", "spans")
+    __slots__ = ("display_id", "frame_id", "t0_ns", "t1_ns", "spans",
+                 "counts")
 
     def __init__(self, display_id: str):
         self.display_id = display_id
         self.frame_id: Optional[int] = None
         self.t0_ns = _now_ns()
         self.t1_ns: Optional[int] = None
-        self.spans: list[tuple[str, str, int, int]] = []
+        self.spans: list[tuple[str, str, int, int, Optional[int]]] = []
+        self.counts: dict[str, int] = {}
 
     @property
     def done(self) -> bool:
@@ -66,13 +95,19 @@ class FrameTimeline:
         return (self.t1_ns - self.t0_ns) / 1e6
 
     def to_dict(self) -> dict:
+        spans = []
+        for n, la, t0, d, cpu in self.spans:
+            s = {"name": n, "lane": la, "t0_ns": t0, "dur_ns": d}
+            if cpu is not None:
+                s["cpu_ns"] = cpu
+            spans.append(s)
         return {
             "display_id": self.display_id,
             "frame_id": self.frame_id,
             "t0_ns": self.t0_ns,
             "t1_ns": self.t1_ns,
-            "spans": [{"name": n, "lane": la, "t0_ns": t0, "dur_ns": d}
-                      for n, la, t0, d in self.spans],
+            "spans": spans,
+            "counts": dict(self.counts),
         }
 
 
@@ -95,24 +130,31 @@ _USE_CURRENT = object()
 
 
 class _Span:
-    """Live span context manager bound to one timeline."""
+    """Live span context manager bound to one timeline: a work span
+    (thread CPU time read at both ends) or a wait span (no CPU time, on
+    the wait lane)."""
 
-    __slots__ = ("_tracer", "_tl", "_name", "_lane", "_t0")
+    __slots__ = ("_tracer", "_tl", "_name", "_lane", "_wait", "_t0",
+                 "_c0")
 
     def __init__(self, tracer_: "FrameTracer", tl: FrameTimeline,
-                 name: str, lane: Optional[str]):
+                 name: str, lane: Optional[str], wait: bool):
         self._tracer = tracer_
         self._tl = tl
         self._name = name
-        self._lane = lane
+        self._lane = wait_lane(lane) if wait else lane
+        self._wait = wait
 
     def __enter__(self):
+        self._c0 = None if self._wait else _cpu_ns()
         self._t0 = _now_ns()
         return self
 
     def __exit__(self, *exc):
+        t1 = _now_ns()
+        cpu = None if self._c0 is None else _cpu_ns() - self._c0
         self._tracer._record(self._tl, self._name, self._lane,
-                             self._t0, _now_ns() - self._t0)
+                             self._t0, t1 - self._t0, cpu)
         return False
 
 
@@ -207,21 +249,30 @@ class FrameTracer:
         if _current.get() is tl:
             _current.set(None)
 
+    def current(self) -> Optional[FrameTimeline]:
+        """The timeline this thread/task is dispatching, or None (also
+        whenever the tracer is off)."""
+        if not self._enabled:
+            return None
+        return _current.get()
+
     # -- spans ---------------------------------------------------------------
-    def span(self, name: str, tl=_USE_CURRENT, lane: Optional[str] = None):
+    def span(self, name: str, tl=_USE_CURRENT, lane: Optional[str] = None,
+             wait: bool = False):
         """Context manager timing one stage. Targets ``tl`` when given;
         defaults to the current dispatch context. No-op when disabled,
         when no context exists (engine code runs unchanged under scripts
         that never call frame_begin), or when ``tl`` is explicitly None
         (a finalize whose frame already left the ring must NOT fall back
-        to the current context — that is a different, newer frame)."""
+        to the current context — that is a different, newer frame).
+        ``wait=True`` records a wait span on ``<lane>.wait``."""
         if not self._enabled:
             return _NULL_SPAN
         if tl is _USE_CURRENT:
             tl = _current.get()
         if tl is None:
             return _NULL_SPAN
-        return _Span(self, tl, name, lane)
+        return _Span(self, tl, name, lane, wait)
 
     def attach_span(self, display_id: str, frame_id: int, name: str,
                     t0_ns: int, dur_ns: int,
@@ -248,14 +299,29 @@ class FrameTracer:
         return True
 
     def record_span(self, tl: Optional[FrameTimeline], name: str,
-                    t0_ns: int, lane: Optional[str] = None) -> None:
-        """Record a span with an EXPLICIT start ending now — the deep
-        pipeline's readback span starts at the slot's submit instant
-        (frames-in-flight time is readback time, not bubble), which no
-        context manager entered on this thread can know."""
+                    t0_ns: int, lane: Optional[str] = None, *,
+                    end_ns: Optional[int] = None,
+                    wait: bool = False) -> None:
+        """Record a span with an EXPLICIT start, ending at ``end_ns`` or
+        now — the deep pipeline's readback span starts at the slot's
+        submit instant (frames-in-flight time is readback time, not
+        bubble), which no context manager entered on this thread can
+        know; a queue's wait runs between two stamps. ``wait=True`` puts
+        it on ``<lane>.wait``."""
         if tl is None or not self._enabled:
             return
-        self._record(tl, name, lane, t0_ns, max(0, _now_ns() - t0_ns))
+        if end_ns is None:
+            end_ns = _now_ns()
+        self._record(tl, name, wait_lane(lane) if wait else lane, t0_ns,
+                     max(0, end_ns - t0_ns))
+
+    def count(self, tl: Optional[FrameTimeline], name: str, n: int) -> None:
+        """Add ``n`` to the frame's counter ``name`` (bytes fetched, rows
+        dispatched…). No-op when ``tl`` is None or the tracer is off."""
+        if tl is None or not self._enabled:
+            return
+        counts = tl.counts
+        counts[name] = counts.get(name, 0) + int(n)
 
     def instant(self, display_id: str, frame_id: int, name: str,
                 lane: Optional[str] = None) -> bool:
@@ -271,10 +337,11 @@ class FrameTracer:
             return self._ring.get((display_id, int(frame_id)))
 
     def _record(self, tl: FrameTimeline, name: str, lane: Optional[str],
-                t0_ns: int, dur_ns: int) -> None:
+                t0_ns: int, dur_ns: int, cpu_ns: Optional[int] = None
+                ) -> None:
         if lane is None:
             lane = threading.current_thread().name
-        tl.spans.append((name, lane, t0_ns, dur_ns))
+        tl.spans.append((name, lane, t0_ns, dur_ns, cpu_ns))
         sink = self.stage_sink
         if sink is not None and dur_ns > 0:
             try:

@@ -3,8 +3,10 @@
 Emits the JSON Object Format: ``{"traceEvents": [...], "displayTimeUnit":
 "ms"}`` with complete (``ph: "X"``) events for spans, instant (``ph:
 "i"``) events for zero-duration markers, and ``M`` metadata events naming
-the process and one thread track per lane. Timestamps are microseconds of
-``perf_counter`` — relative, monotonic, exactly what the viewers expect.
+the process and one thread track per lane (a wait lane ``<lane>.wait`` is
+a track of its own). Timestamps are microseconds of ``CLOCK_MONOTONIC``,
+the tracer's clock. A work span's ``cpu_ns`` rides its ``args``; the
+frame's counters ride the frame envelope's ``args.counts``.
 """
 
 from __future__ import annotations
@@ -48,10 +50,13 @@ def to_trace_events(timelines: Iterable[Union[FrameTimeline, dict]],
         fid = d.get("frame_id")
         frame_args = {"frame_id": fid, "display": d.get("display_id")}
         if d.get("t1_ns") is not None:
+            env_args = frame_args
+            if d.get("counts"):
+                env_args = dict(frame_args, counts=dict(d["counts"]))
             events.append({
                 "name": f"frame {fid}", "ph": "X", "pid": PID,
                 "tid": FRAME_TID, "ts": d["t0_ns"] / 1e3,
-                "dur": (d["t1_ns"] - d["t0_ns"]) / 1e3, "args": frame_args,
+                "dur": (d["t1_ns"] - d["t0_ns"]) / 1e3, "args": env_args,
             })
         for s in d.get("spans", []):
             tid = tid_for(s["lane"])
@@ -61,10 +66,13 @@ def to_trace_events(timelines: Iterable[Union[FrameTimeline, dict]],
                     "tid": tid, "ts": s["t0_ns"] / 1e3, "args": frame_args,
                 })
             else:
+                args = frame_args
+                if s.get("cpu_ns") is not None:
+                    args = dict(frame_args, cpu_ns=s["cpu_ns"])
                 events.append({
                     "name": s["name"], "ph": "X", "pid": PID, "tid": tid,
                     "ts": s["t0_ns"] / 1e3, "dur": s["dur_ns"] / 1e3,
-                    "args": frame_args,
+                    "args": args,
                 })
     return {"traceEvents": events, "displayTimeUnit": "ms"}
 
@@ -101,6 +109,7 @@ def timelines_from_events(events: Iterable[dict]) -> list[dict]:
                 "t0_ns": int(float(e["ts"]) * 1e3),
                 "t1_ns": int((float(e["ts"]) + float(e["dur"])) * 1e3),
                 "spans": [],
+                "counts": dict(args.get("counts") or {}),
             }
         else:
             spans.append((key, e, (e.get("pid"), e.get("tid"))))
@@ -108,12 +117,16 @@ def timelines_from_events(events: Iterable[dict]) -> list[dict]:
         tl = frames.get(key)
         if tl is None:
             continue
-        tl["spans"].append({
+        span = {
             "name": str(e.get("name", "?")),
             "lane": thread_names.get(tkey) or str(tkey[1]),
             "t0_ns": int(float(e["ts"]) * 1e3),
             "dur_ns": int(float(e.get("dur", 0)) * 1e3),
-        })
+        }
+        cpu = (e.get("args") or {}).get("cpu_ns")
+        if cpu is not None:
+            span["cpu_ns"] = int(cpu)
+        tl["spans"].append(span)
     return [frames[k] for k in sorted(frames, key=lambda k: frames[k]["t0_ns"])]
 
 

@@ -14,7 +14,9 @@ Two instruments over the same frame timelines:
   is charged to the covering span that ends last — the stage still
   gating completion — or to ``bubble`` when nothing runs), an overlap
   fraction (0 for a fully-serial pipeline), and per-lane occupancy /
-  largest-gap detection over the whole timeline window.
+  largest-gap detection over the whole timeline window. Wait spans (on
+  ``<lane>.wait``) are no work: these views leave them out, so a frame's
+  time spent only waiting reads as bubble.
 
 Consumes either live :class:`~.core.FrameTimeline`s or the exported
 Chrome trace-event JSON (the offline CLI path), so a BENCH_r*.json
@@ -25,7 +27,7 @@ from __future__ import annotations
 
 from typing import Iterable, Optional, Union
 
-from .core import FrameTimeline
+from .core import WAIT_SUFFIX, FrameTimeline
 
 
 def _pct(sorted_vals: list[float], q: float) -> float:
@@ -55,12 +57,20 @@ def summarize_durations(by_stage: dict[str, list[float]]) -> dict[str, dict]:
                        key=lambda kv: -kv[1]["total_ms"]))
 
 
-def summarize_timelines(timelines: Iterable[Union[FrameTimeline, dict]]
-                        ) -> dict[str, dict]:
+def _is_wait(span: dict) -> bool:
+    return (span.get("lane") or "").endswith(WAIT_SUFFIX)
+
+
+def summarize_timelines(timelines: Iterable[Union[FrameTimeline, dict]],
+                        waits: bool = True) -> dict[str, dict]:
+    """Per-stage percentiles; ``waits=False`` leaves out the spans on
+    wait lanes (``<lane>.wait``)."""
     by_stage: dict[str, list[float]] = {}
     for tl in timelines:
         d = tl if isinstance(tl, dict) else tl.to_dict()
         for s in d.get("spans", []):
+            if not waits and _is_wait(s):
+                continue
             if s["dur_ns"] > 0:
                 by_stage.setdefault(s["name"], []).append(s["dur_ns"] / 1e6)
     return summarize_durations(by_stage)
@@ -104,7 +114,8 @@ def frame_critical_path(tl: Union[FrameTimeline, dict]) -> Optional[dict]:
     Every instant of ``[t0, t1]`` is charged to exactly one account:
     the covering span that ends last (several stages running at once —
     the one finishing last is the one gating completion), or
-    :data:`BUBBLE` when no span covers it. By construction
+    :data:`BUBBLE` when no work span covers it (waits are left out). By
+    construction
     ``sum(stages) + bubble == e2e`` exactly; for a fully-serial pipeline
     each stage's charge equals its duration, so the critical path
     equals the stage sum.
@@ -119,7 +130,7 @@ def frame_critical_path(tl: Union[FrameTimeline, dict]) -> Optional[dict]:
     t0f, t1f = d["t0_ns"], d["t1_ns"]
     ivs: list[tuple[int, int, str]] = []
     for s in d.get("spans", []):
-        if s["dur_ns"] <= 0:
+        if s["dur_ns"] <= 0 or _is_wait(s):
             continue
         a = max(s["t0_ns"], t0f)
         b = min(s["t0_ns"] + s["dur_ns"], t1f)
@@ -196,7 +207,8 @@ def lane_occupancy(timelines: Iterable[Union[FrameTimeline, dict]]
     deep-pipeline acceptance view: after the rework every lane should
     stay busy (occupancy -> 1 for the bottleneck lane) instead of the
     frame-serial pattern where every lane idles while one works.
-    ``largest_gap_ms`` is the worst bubble inside the window."""
+    ``largest_gap_ms`` is the worst bubble inside the window. Wait lanes
+    are not reported: a thread blocked is no lane busy."""
     by_lane: dict[str, list[tuple[int, int]]] = {}
     w0: Optional[int] = None
     w1: Optional[int] = None
@@ -207,7 +219,7 @@ def lane_occupancy(timelines: Iterable[Union[FrameTimeline, dict]]
         w0 = d["t0_ns"] if w0 is None else min(w0, d["t0_ns"])
         w1 = d["t1_ns"] if w1 is None else max(w1, d["t1_ns"])
         for s in d.get("spans", []):
-            if s["dur_ns"] > 0:
+            if s["dur_ns"] > 0 and not _is_wait(s):
                 by_lane.setdefault(s.get("lane") or "?", []).append(
                     (s["t0_ns"], s["t0_ns"] + s["dur_ns"]))
     if w0 is None or w1 is None or w1 <= w0:
@@ -246,16 +258,24 @@ def window_overlap_fraction(dicts: list) -> float:
     ``encode.dispatch`` under frame N's readback/packetize. This is THE
     deep-pipeline acceptance number (ROADMAP 2): per-frame stages of a
     pipelined engine still run in sequence *within* each frame, so only
-    the window view can see the overlap."""
+    the window view can see the overlap. A span nested in another of
+    its frame on the same lane (``readback.copy`` in ``encode.readback``)
+    is no overlap, and waits (``<lane>.wait``) are no work: each frame's
+    lane counts as the union of its spans, wait lanes not at all."""
     ivs: list[tuple[int, int]] = []
     total = 0
     for d in dicts:
         if d.get("t1_ns") is None:
             continue
+        by_lane: dict[str, list[tuple[int, int]]] = {}
         for s in d.get("spans", []):
-            if s["dur_ns"] > 0:
-                ivs.append((s["t0_ns"], s["t0_ns"] + s["dur_ns"]))
-                total += s["dur_ns"]
+            if s["dur_ns"] > 0 and not _is_wait(s):
+                by_lane.setdefault(s.get("lane") or "?", []).append(
+                    (s["t0_ns"], s["t0_ns"] + s["dur_ns"]))
+        for lane_ivs in by_lane.values():
+            merged = _merge_intervals(lane_ivs)
+            ivs.extend(merged)
+            total += sum(b - a for a, b in merged)
     if total <= 0:
         return 0.0
     union = sum(b - a for a, b in _merge_intervals(ivs))

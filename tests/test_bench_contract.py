@@ -64,11 +64,14 @@ def test_bench_emits_single_json_line():
     # asserted) at every depth. The PIPELINED phase is covered by the
     # occupancy identity instead (test_bench_occupancy_block), where
     # stage sum exceeding e2e is the point.
-    from selkies_tpu.trace import STAGES
+    # The sum is over the frame stages, which never nest: the nested
+    # spans (readback.copy, the waits) are parts of them.
+    from selkies_tpu.trace import FRAME_STAGES, STAGES
     assert set(doc["stages_ms"]) == set(STAGES)
     stage_sum = doc["stage_sum_ms"]
     e2e = doc["latency_mean_ms"]
-    assert stage_sum == round(sum(doc["stages_ms"].values()), 3)
+    assert stage_sum == round(sum(doc["stages_ms"][s] for s in FRAME_STAGES),
+                              3)
     assert abs(stage_sum - e2e) <= 0.2 * e2e, \
         f"stage sum {stage_sum}ms vs e2e {e2e}ms: uninstrumented stall"
 

@@ -110,6 +110,26 @@ def test_occupancy_report_aggregates_and_renders():
     assert "encode.dispatch" in text and "overlap=0.0%" in text
 
 
+def test_occupancy_report_reads_waits_as_no_work():
+    """A wait span (on ``<lane>.wait``) is neither a busy lane nor a
+    critical-path stage: the report reads as if it were not there, so a
+    wait outside every work span stays bubble."""
+    work = [("capture", "cap", 0, 4 * MS),
+            ("encode.dispatch", "cap", 4 * MS, 6 * MS),
+            ("packetize", "slot0", 14 * MS, 6 * MS)]
+    waits = [("encode.probe_sync", "cap.wait", 5 * MS, 4 * MS),
+             ("pipeline.queue", "slot0.wait", 10 * MS, 4 * MS),
+             ("relay.queue", "ws.wait", 20 * MS, 5 * MS)]
+    plain = occupancy_report([_tl(1, 0, 25 * MS, work)])
+    rep = occupancy_report([_tl(1, 0, 25 * MS, work + waits)])
+    assert rep == plain
+    assert set(rep["lanes"]) == {"cap", "slot0"}
+    assert set(rep["critical_path"]) == {"capture", "encode.dispatch",
+                                         "packetize"}
+    # [10, 14] and [20, 25] hold only waits: 9 of 25 ms
+    assert rep["bubble_share"] == pytest.approx(9 / 25)
+
+
 def test_lane_occupancy_detects_bubbles():
     """Two frames pipelined on two lanes: the cap lane works [0,4] and
     [10,14] inside a [0,20] window -> 40% occupancy, 6 ms worst gap."""

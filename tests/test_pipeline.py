@@ -188,6 +188,142 @@ def test_h264_pipelined_byte_identical_to_serial(stream):
     assert serial == piped
 
 
+def _session(codec):
+    from selkies_tpu.engine.encoder import JpegEncoderSession
+    from selkies_tpu.engine.h264_encoder import H264EncoderSession
+    if codec == "jpeg":
+        return JpegEncoderSession(CaptureSettings(**SMALL))
+    return H264EncoderSession(CaptureSettings(**dict(
+        SMALL, output_mode="h264", video_crf=28)))
+
+
+def _run_traced(sess, frames, stream):
+    """Depth 2 with the frame timelines a capture loop keeps: -> (chunks,
+    the dispatched ``out`` dicts, their timelines)."""
+    from selkies_tpu.trace import tracer
+    disp = sess.settings.display_id
+    got, outs, tls = [], [], []
+
+    def fin(out):
+        force_all = out.pop("force_all")
+        if stream:
+            got.extend(sess.finalize_stream(out, force_all=force_all))
+        else:
+            got.extend(sess.finalize(out, force_all=force_all))
+        tracer.frame_end(disp, out["frame_id"])
+
+    ring = PipelineRing(fin, depth=2)
+    for t, f in enumerate(frames):
+        tl = tracer.frame_begin(disp)
+        out = sess.encode(f, force=(t == 0))
+        tracer.bind(tl, out["frame_id"])
+        out["force_all"] = t == 0
+        outs.append(out)
+        tls.append(tl)
+        ring.submit(out, tl=tl)
+    ring.close(drain=True)
+    return [(c.frame_id, c.stripe_y, c.payload) for c in got], outs, tls
+
+
+def _inside(inner, outer) -> bool:
+    return outer["t0_ns"] <= inner["t0_ns"] and \
+        inner["t0_ns"] + inner["dur_ns"] <= outer["t0_ns"] + outer["dur_ns"]
+
+
+@pytest.mark.parametrize("stream", [False, True],
+                         ids=["batch", "stripe-streaming"])
+@pytest.mark.parametrize("codec", ["jpeg", "h264"])
+def test_traced_frames_hold_their_waits_nested(codec, stream, monkeypatch):
+    """Every dispatched frame's timeline holds its waits on ``<lane>.wait``
+    inside the span they belong to; ``encode.readback`` still starts at
+    the slot's submit; the bytes are those of an untraced run."""
+    from selkies_tpu.engine import readback
+    from selkies_tpu.engine.sources import SyntheticSource
+    from selkies_tpu.trace import tracer
+    # the device path's bucketed slice, as on a chip (the CPU backend's
+    # buffers are host memory, fetched without one)
+    monkeypatch.setattr(readback, "_on_host", lambda arr: False)
+    frames = _frames(SyntheticSource, 8, 64, 64)
+    plain = _run_pipelined(_session(codec), frames, depth=2, stream=stream)
+    tracer.clear()
+    tracer.enable(capacity=64)
+    try:
+        traced, outs, tls = _run_traced(_session(codec), frames, stream)
+    finally:
+        tracer.disable()
+    try:
+        assert traced == plain
+        me = threading.current_thread().name
+        for out, tl in zip(outs, tls):
+            d = tl.to_dict()
+            spans = d["spans"]
+            lane = f"slot{out['slot']}"
+
+            def named(name):
+                return [s for s in spans if s["name"] == name]
+
+            env, = [s for s in named("encode.readback")
+                    if s["t0_ns"] == out["submitted_ns"]]
+            assert env["lane"] == lane
+            admit, = named("pipeline.admit")
+            queue, = named("pipeline.queue")
+            assert admit["lane"] == me + ".wait"
+            assert queue["lane"] == lane + ".wait"
+            assert admit["t0_ns"] == out["submitted_ns"]
+            assert admit["t0_ns"] + admit["dur_ns"] == queue["t0_ns"]
+            for w in (admit, queue):
+                assert _inside(w, env)
+            if out.get("idle"):
+                assert not named("readback.device_wait")
+                continue
+            sync, = named("readback.device_wait")
+            assert sync["lane"] == lane + ".wait" and _inside(sync, env)
+            copies = named("readback.copy")
+            waits = named("readback.slice_wait")
+            if not any(c[0] == out["frame_id"] for c in traced):
+                assert not copies and "readback.bytes" not in d["counts"]
+                continue           # nothing damaged: nothing fetched
+            assert copies and len(waits) == len(copies)
+            assert d["counts"]["readback.bytes"] > 0
+            for c in copies:
+                assert c["lane"] == lane and "cpu_ns" in c
+                assert any(_inside(c, r) for r in named("encode.readback"))
+                assert any(_inside(w, c) for w in waits)
+            assert {w["lane"] for w in waits} == {lane + ".wait"}
+            if codec == "h264" and not out.get("intra"):
+                probe, = named("encode.probe_sync")
+                dispatch, = named("encode.dispatch")
+                assert probe["lane"] == me + ".wait"
+                assert _inside(probe, dispatch)
+    finally:
+        tracer.clear()
+
+
+@pytest.mark.parametrize("codec", ["jpeg", "h264"])
+def test_tracer_off_reads_no_thread_time_and_syncs_nothing(codec,
+                                                           monkeypatch):
+    """With the tracer off the session and the ring never read thread CPU
+    time nor wait on a device array beyond what the transfer syncs."""
+    import jax
+
+    from selkies_tpu.engine import readback
+    from selkies_tpu.engine.sources import SyntheticSource
+    from selkies_tpu.trace import core, tracer
+
+    def forbidden(*a, **k):
+        raise AssertionError("called with the tracer off")
+
+    assert not tracer.enabled
+    monkeypatch.setattr(readback, "_on_host", lambda arr: False)
+    monkeypatch.setattr(core, "_cpu_ns", forbidden)
+    monkeypatch.setattr(type(jax.numpy.zeros(1)), "block_until_ready",
+                        forbidden)
+    frames = _frames(SyntheticSource, 6, 64, 64)
+    for stream in (False, True):
+        chunks, _, tls = _run_traced(_session(codec), frames, stream)
+        assert chunks and tls == [None] * len(frames)
+
+
 def test_sessions_tolerate_caller_reusing_frame_arrays():
     """Donation discipline: the step donates only session-owned state,
     never the caller's frame — a source handing back the SAME device
@@ -353,6 +489,28 @@ def test_window_overlap_sees_cross_frame_concurrency():
     assert rep["overlap_fraction"] == pytest.approx(0.25)
     # per-frame identity still exact: shares + bubble account for e2e
     assert rep["bubble_share"] == 0.0
+
+
+def test_window_overlap_ignores_nested_spans_and_waits():
+    """A copy nested in its readback on the same lane, and the waits on
+    their own lanes, are no cross-frame overlap: serial frames stay 0."""
+    from selkies_tpu.trace.summary import window_overlap_fraction
+    MS = 1_000_000
+
+    def frame(t):
+        return {"t0_ns": t * MS, "t1_ns": (t + 10) * MS, "spans": [
+            {"name": "encode.dispatch", "lane": "cap", "t0_ns": t * MS,
+             "dur_ns": 4 * MS},
+            {"name": "encode.probe_sync", "lane": "cap.wait",
+             "t0_ns": t * MS, "dur_ns": 3 * MS},
+            {"name": "encode.readback", "lane": "slot0",
+             "t0_ns": (t + 4) * MS, "dur_ns": 6 * MS},
+            {"name": "readback.copy", "lane": "slot0",
+             "t0_ns": (t + 8) * MS, "dur_ns": 2 * MS},
+            {"name": "pipeline.queue", "lane": "slot0.wait",
+             "t0_ns": (t + 4) * MS, "dur_ns": 2 * MS}]}
+
+    assert window_overlap_fraction([frame(0), frame(10)]) == 0.0
 
 
 # ----------------------------------------------------------- ladder rung 0

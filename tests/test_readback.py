@@ -71,3 +71,67 @@ def test_fetch_stripe_seat_axis_preserved():
     full = rng.integers(0, 256, (2, 2 * MIN_STRIPE_BUCKET), dtype=np.uint8)
     got = fetch_stripe_bytes(jnp.asarray(full), 123, 456)
     assert np.array_equal(got, full[:, 123:123 + 456])
+
+
+# -- traced fetches: counted, the slice's wait apart from the copy -----------
+
+def _traced_timeline():
+    from selkies_tpu.trace import tracer
+    tracer.enable(capacity=16)
+    tl = tracer.frame_begin(":rb")
+    tracer.bind(tl, 1)
+    return tracer, tl
+
+
+def test_traced_device_fetch_counts_the_buckets(monkeypatch):
+    """On the device path (forced here: the CPU backend is host memory)
+    ``readback.bytes`` is the sum of the buckets that crossed, and each
+    bucketed slice's wait is a ``readback.slice_wait`` on the lane's
+    wait track."""
+    import jax.numpy as jnp
+
+    from selkies_tpu.engine import readback
+    monkeypatch.setattr(readback, "_on_host", lambda arr: False)
+    rng = np.random.default_rng(5)
+    n = 8 * MIN_BUCKET
+    full = rng.integers(0, 256, (n,), dtype=np.uint8)
+    dev = jnp.asarray(full)
+    tracer, tl = _traced_timeline()
+    try:
+        asks = [(None, 1000), (None, MIN_BUCKET + 1), (0, 300),
+                (77, MIN_STRIPE_BUCKET + 5), (n - 10, 10)]
+        for start, length in asks:
+            if start is None:
+                got = readback.fetch_stream_bytes(dev, length, tl=tl,
+                                                  lane="slot1")
+                assert np.array_equal(got[:length], full[:length])
+            else:
+                got = readback.fetch_stripe_bytes(dev, start, length, tl=tl,
+                                                  lane="slot1")
+                assert np.array_equal(got, full[start:start + length])
+        buckets = [bucket_for(length) if start is None
+                   else bucket_for(length, MIN_STRIPE_BUCKET)
+                   for start, length in asks]
+        assert tl.counts == {"readback.bytes": sum(buckets)}
+        waits = [s for s in tl.spans if s[0] == "readback.slice_wait"]
+        assert len(waits) == len(asks)
+        assert {s[1] for s in waits} == {"slot1.wait"}
+    finally:
+        tracer.disable()
+        tracer.clear()
+
+
+def test_traced_host_fetch_counts_what_it_copies():
+    """On the host path nothing is bucketed and no slice is waited for:
+    the bytes counted are the bytes sliced, per seat row."""
+    import jax.numpy as jnp
+    full = np.arange(2 * 512, dtype=np.uint8).reshape(2, 512)
+    tracer, tl = _traced_timeline()
+    try:
+        got = fetch_stripe_bytes(jnp.asarray(full), 100, 50, tl=tl)
+        assert np.array_equal(got, full[:, 100:150])
+        assert tl.counts == {"readback.bytes": 100}
+        assert tl.spans == []
+    finally:
+        tracer.disable()
+        tracer.clear()

@@ -5,6 +5,7 @@ fingerprint satellite (ISSUE 2)."""
 
 import json
 import os
+import threading
 import time
 import tracemalloc
 import types
@@ -13,8 +14,9 @@ from selkies_tpu import compile_cache
 from selkies_tpu.trace import STAGES
 from selkies_tpu.trace import tracer as global_tracer
 from selkies_tpu.trace.__main__ import main as trace_cli
-from selkies_tpu.trace.core import _NULL_SPAN, FrameTracer
-from selkies_tpu.trace.export import events_from_document, to_trace_events
+from selkies_tpu.trace.core import _NULL_SPAN, FrameTracer, now_ns
+from selkies_tpu.trace.export import (events_from_document,
+                                      timelines_from_events, to_trace_events)
 from selkies_tpu.trace.summary import (frame_latency_ms, render_table,
                                        summarize_durations,
                                        summarize_events,
@@ -36,7 +38,7 @@ def test_span_nesting_and_ordering():
     assert tl.done and tl.frame_id == 1
     names = [s[0] for s in tl.spans]
     assert names == ["inner", "outer"]      # exit order: inner closes first
-    spans = {n: (t0, dur) for n, _lane, t0, dur in tl.spans}
+    spans = {n: (t0, dur) for n, _lane, t0, dur, _cpu in tl.spans}
     o_t0, o_dur = spans["outer"]
     i_t0, i_dur = spans["inner"]
     assert o_t0 <= i_t0 and i_t0 + i_dur <= o_t0 + o_dur   # containment
@@ -80,21 +82,162 @@ def test_disabled_mode_no_allocation_beyond_flag_check():
     # the disabled span is one shared singleton — identity proves no
     # per-call allocation
     assert tr.span("a") is tr.span("b") is _NULL_SPAN
+    assert tr.span("w", wait=True) is _NULL_SPAN
     assert tr.frame_begin(":0") is None
+    assert tr.current() is None
     tr.bind(None, 1)
     tr.frame_end(":0", 1)
     assert not tr.attach_span(":0", 1, "x", 0, 1)
-    # a full per-frame call sequence retains nothing
+    # a timeline begun while on takes nothing once the tracer is off
+    tr.enable()
+    held = tr.frame_begin(":0")
+    tr.disable()
+    # a full per-frame call sequence retains nothing, the counters, the
+    # explicit-end record and the wait lane included
     tracemalloc.start()
     for _ in range(1000):
         with tr.span("x"):
             pass
+        with tr.span("w", held, lane="slot0", wait=True):
+            pass
+        tr.count(held, "readback.bytes", 4096)
+        tr.count(None, "readback.bytes", 4096)
+        tr.record_span(held, "pipeline.queue", 0, lane="slot0", end_ns=5,
+                       wait=True)
         t = tr.frame_begin(":0")
+        tr.current()
         tr.bind(t, 0)
         tr.frame_end(":0", 0)
     current, _peak = tracemalloc.get_traced_memory()
     tracemalloc.stop()
     assert current < 2048, f"disabled tracer retained {current} bytes"
+    assert held.spans == [] and held.counts == {}
+
+
+def test_wait_spans_ride_the_wait_lane_without_cpu_time():
+    tr = FrameTracer()
+    tr.enable()
+    tl = tr.frame_begin(":0")
+    tr.bind(tl, 1)
+    with tr.span("encode.dispatch", tl):
+        with tr.span("encode.probe_sync", tl, wait=True):
+            time.sleep(0.002)
+    with tr.span("packetize", tl, lane="slot1"):
+        with tr.span("readback.slice_wait", tl, lane="slot1", wait=True):
+            pass
+    tr.record_span(tl, "pipeline.queue", 1_000, lane="slot1",
+                   end_ns=4_000, wait=True)
+    tr.record_span(tl, "encode.readback", 1_000, lane="slot1",
+                   end_ns=9_000)
+    me = threading.current_thread().name
+    spans = {s["name"]: s for s in tl.to_dict()["spans"]}
+    assert spans["encode.probe_sync"]["lane"] == me + ".wait"
+    assert spans["readback.slice_wait"]["lane"] == "slot1.wait"
+    assert spans["pipeline.queue"]["lane"] == "slot1.wait"
+    assert (spans["pipeline.queue"]["t0_ns"],
+            spans["pipeline.queue"]["dur_ns"]) == (1_000, 3_000)
+    assert spans["encode.readback"]["lane"] == "slot1"
+    # work spans measured on this thread carry its CPU time; waits and
+    # spans measured from stamps carry none
+    for name in ("encode.dispatch", "packetize"):
+        assert 0 <= spans[name]["cpu_ns"] <= spans[name]["dur_ns"] + 1e6
+    for name in ("encode.probe_sync", "readback.slice_wait",
+                 "pipeline.queue", "encode.readback"):
+        assert "cpu_ns" not in spans[name], name
+    # sleeping is off the CPU: the dispatch's CPU time is far below its
+    # wall time, which holds the 2 ms wait
+    assert spans["encode.dispatch"]["cpu_ns"] < 1_000_000
+    assert spans["encode.dispatch"]["dur_ns"] >= 2_000_000
+
+
+def test_spans_read_the_monotonic_clock():
+    tr = FrameTracer()
+    tr.enable()
+    tl = tr.frame_begin(":0")
+    before = time.monotonic_ns()
+    with tr.span("capture", tl):
+        pass
+    after = time.monotonic_ns()
+    (_, _, t0, dur, _), = tl.spans
+    assert before <= t0 and t0 + dur <= after
+    # stamps taken for spans recorded later read the same clock
+    assert after <= now_ns() <= time.monotonic_ns()
+
+
+def test_counts_accumulate_and_ride_the_frame_envelope():
+    tr = FrameTracer()
+    tr.enable()
+    tl = tr.frame_begin(":0")
+    tr.bind(tl, 3)
+    tr.count(tl, "readback.bytes", 32768)
+    tr.count(tl, "readback.bytes", 4096)
+    tr.count(tl, "test.rows", 68)
+    with tr.span("packetize", tl, lane="slot0"):
+        pass
+    tr.frame_end(":0", 3)
+    assert tl.to_dict()["counts"] == {"readback.bytes": 36864,
+                                      "test.rows": 68}
+    doc = json.loads(json.dumps(to_trace_events(tr.snapshot())))
+    env = [e for e in doc["traceEvents"] if e.get("name") == "frame 3"]
+    assert env[0]["args"]["counts"] == {"readback.bytes": 36864,
+                                        "test.rows": 68}
+    work = [e for e in doc["traceEvents"] if e.get("name") == "packetize"]
+    assert work[0]["args"]["cpu_ns"] == tl.spans[0][4]
+    # the export reads back whole: counters and CPU time included
+    back, = timelines_from_events(events_from_document(doc))
+    assert back["counts"] == tl.to_dict()["counts"]
+    assert back["spans"][0]["cpu_ns"] == tl.spans[0][4]
+
+
+def test_stage_latency_health_reads_work_stages_only():
+    """A queue's wait is no stage over budget: /api/health's
+    stage_latency verdict leaves the *.wait lanes out."""
+    from test_server import make_app
+    server, *_ = make_app()
+    global_tracer.enable(capacity=16)
+    try:
+        tl = global_tracer.frame_begin(":0")
+        global_tracer.bind(tl, 1)
+        global_tracer.attach_span(":0", 1, "packetize", 0, 1_000_000,
+                                  lane="slot0")
+        global_tracer.attach_span(":0", 1, "pipeline.queue", 0,
+                                  900_000_000, lane="slot0.wait")
+        global_tracer.frame_end(":0", 1)
+        v = server._check_stage_latency()
+        assert v.status == "ok", v
+        assert "packetize" in v.reason
+    finally:
+        global_tracer.disable()
+        global_tracer.clear()
+
+
+def test_profiler_capture_opens_with_a_clock_mark(monkeypatch):
+    """The capture's first event is a ``selkies.clock`` annotation with
+    the host's CLOCK_MONOTONIC ns, kept for GET /api/trace."""
+    import jax
+
+    from selkies_tpu.obs.profiler import CLOCK_MARK, ProfilerSession
+    marks = []
+
+    class _Annotation:
+        def __init__(self, name, **kw):
+            marks.append((name, kw))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda d: None)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Annotation)
+    sess = ProfilerSession()
+    before = time.monotonic_ns()
+    assert sess.start("/nonexistent-trace-dir")["ok"]
+    after = time.monotonic_ns()
+    (name, kw), = marks
+    assert name == CLOCK_MARK == "selkies.clock"
+    assert before <= kw["host_ns"] == sess.clock_mark_ns <= after
 
 
 def test_bind_aliases_route_multi_seat_attach():
@@ -265,6 +408,79 @@ def test_jpeg_session_records_stage_spans():
         global_tracer.clear()
 
 
+# -- the server's waits: the hop to the loop, the relay queue ----------------
+
+async def test_relay_queue_wait_rides_the_ws_wait_lane():
+    """A stripe's time in the relay queue, from ``offer`` to the pop for
+    its send, is its ``relay.queue`` span on ``ws.wait``."""
+    import asyncio
+
+    from selkies_tpu import protocol as P
+    from selkies_tpu.server.relay import VideoRelay
+    gate = asyncio.Event()
+
+    async def _send(data):
+        await gate.wait()
+
+    idle = VideoRelay(_send, display=":0")
+    idle.offer(P.pack_jpeg_stripe(41, 0, b"\xff\xd8x\xff\xd9"))
+    assert idle._queued_at == {}            # tracer off: no stamps
+    global_tracer.enable(capacity=16)
+    try:
+        tl = global_tracer.frame_begin(":0")
+        global_tracer.bind(tl, 42)
+        relay = VideoRelay(_send, display=":0")
+        relay.start()
+        for y in (0, 64):
+            relay.offer(P.pack_jpeg_stripe(42, y, b"\xff\xd8x\xff\xd9"))
+        await asyncio.sleep(0.02)
+        gate.set()
+        for _ in range(100):
+            await asyncio.sleep(0.005)
+            if sum(s[0] == "ws.send" for s in tl.spans) == 2:
+                break
+        await relay.close()
+        spans = tl.to_dict()["spans"]
+        queued = [s for s in spans if s["name"] == "relay.queue"]
+        sends = [s for s in spans if s["name"] == "ws.send"]
+        assert len(queued) == len(sends) == 2
+        assert {s["lane"] for s in queued} == {"ws.wait"}
+        for q, w in zip(queued, sends):
+            assert q["t0_ns"] + q["dur_ns"] == w["t0_ns"]
+        # the second stripe waited behind the first one's blocked send
+        assert queued[1]["dur_ns"] >= 15_000_000
+        assert relay._queued_at == {}
+    finally:
+        global_tracer.disable()
+        global_tracer.clear()
+
+
+def test_fanout_hop_is_traced_and_calls_the_instance_fanout():
+    """The traced hop records ``fanout.hop`` on ``loop.wait`` and then
+    runs ``_do_fanout`` as looked up on the instance at call time (a
+    wrapper put there, as the benchmark's tap is, sees every chunk)."""
+    from selkies_tpu.engine.types import EncodedChunk
+    from test_server import make_app
+    _server, svc, *_ = make_app()
+    seen = []
+    svc._do_fanout = seen.append
+    chunk = EncodedChunk(b"x", 5, 0, 64, 64, True, "jpeg")
+    global_tracer.enable(capacity=16)
+    try:
+        tl = global_tracer.frame_begin(":0")
+        global_tracer.bind(tl, 5)
+        t_hop = now_ns() - 2_000_000
+        svc._fanout_hopped(chunk, t_hop)
+        assert seen == [chunk]
+        (name, lane, t0, dur, cpu), = tl.spans
+        assert (name, lane, t0, cpu) == ("fanout.hop", "loop.wait", t_hop,
+                                         None)
+        assert dur >= 2_000_000
+    finally:
+        global_tracer.disable()
+        global_tracer.clear()
+
+
 # -- /api/trace endpoint contract ---------------------------------------------
 
 async def test_api_trace_endpoint(client_factory):
@@ -290,6 +506,8 @@ async def test_api_trace_endpoint(client_factory):
         names = [e.get("name") for e in events]
         assert "capture" in names and "frame 7" in names
         assert doc["otherData"]["frames"] == 1
+        # the last /api/profile capture's clock mark, None before any
+        assert "clock_mark_ns" in doc["otherData"]
         r = await c.post("/api/trace", json={"action": "clear"})
         assert (await r.json())["frames"] == 0
         r = await c.post("/api/trace", json={"action": "stop"})
