@@ -599,8 +599,8 @@ class ScreenCapture:
         """Finalize + hand chunks to the callback. Runs on the ring's
         finalizer thread at depth >= 2, inline at depth 1; either way
         strictly in submission order. With ``stripe_streaming`` each
-        stripe ships AS ITS BYTES LAND (per-stripe fetch) instead of
-        after the frame barrier."""
+        stripe ships as soon as it is cut from the frame's one fetch and
+        wrapped, instead of after the whole frame is wrapped."""
         sess = self._session
         assert sess is not None
         s = self._settings

@@ -319,16 +319,47 @@ class JpegEncoderSession:
 
     def finalize(self, out: dict[str, Any], force_all: bool = False
                  ) -> list[EncodedChunk]:
-        """Blocks on the async readback and produces wire-ready chunks."""
+        """Blocks on the async readback and produces wire-ready chunks
+        (one ``packetize`` span for the frame)."""
+        got = self._readback(out, force_all)
+        if got is None:
+            return []
+        tl, lane, deliver, chunk = got
+        with _tracer.span("packetize", tl, lane=lane):
+            return [chunk(i) for i in deliver]
+
+    def finalize_stream(self, out: dict[str, Any], force_all: bool = False):
+        """Stripe-granular finalize (deep pipeline, ROADMAP 2): the same
+        one fetch per frame as :meth:`finalize`, then each stripe is cut
+        from the host copy, JFIF-wrapped and yielded, so the fanout ships
+        the first stripe while later ones are still being wrapped.
+        Byte-identical to :meth:`finalize` (tests pin it for both
+        codecs)."""
+        got = self._readback(out, force_all)
+        if got is None:
+            return
+        tl, lane, deliver, chunk = got
+        for i in deliver:
+            with _tracer.span("packetize", tl, lane=lane):
+                c = chunk(i)
+            yield c
+
+    def _readback(self, out: dict[str, Any], force_all: bool):
+        """The frame's readback, shared by finalize and finalize_stream:
+        the control sync, then ONE device fetch
+        (engine/readback.fetch_stripe_bytes) over the delivered stripes,
+        from the first one's start to the last one's end — clean stripes
+        outside it and the capacity padding never cross the host link,
+        and a round trip per stripe would cost more than its bytes.
+        -> None when there is nothing to send (overflow, idle frame),
+        else (tl, lane, the delivered stripe indices, chunk(i))."""
         g = self.grid
         # trace target: THIS frame's timeline, by id — never the current
         # dispatch context, which is up to pipeline_depth frames ahead.
-        # ONE readback span per frame on this (batch) path, from the
-        # slot's SUBMIT instant (in-flight time is readback time, not
-        # bubble; serial calls start here): the ring's admission and
-        # queue, the control sync (readback.device_wait) and the stream
-        # fetch (readback.copy). The streaming path (finalize_stream)
-        # adds a span per stripe fetch instead — totals stay identical.
+        # ONE readback span per frame, from the slot's SUBMIT instant
+        # (in-flight time is readback time, not bubble; serial calls
+        # start here): the ring's admission and queue, the control sync
+        # (readback.device_wait) and the stream fetch (readback.copy)
         tl = _tracer.lookup(self.settings.display_id, out["frame_id"])
         # per-slot lane (deep pipeline): occupancy attribution must see
         # WHICH in-flight slot ran, not just "the finalizer thread"
@@ -336,72 +367,29 @@ class JpegEncoderSession:
         rb_t0 = out.get("submitted_ns") or time.monotonic_ns()
         overflowed, idle, force_all, lens, send, is_paint = \
             self._sync_control(out, force_all, tl, lane)
-        data = None
         if not overflowed and not idle:
+            deliver = range(g.n_stripes) if force_all \
+                else [int(i) for i in np.nonzero(send)[0]]
             starts = np.concatenate([[0], np.cumsum(lens)])
-            # minimal readback (engine/readback.py): all stripes
-            # are always in the buffer, so the used prefix is
-            # everything up to the last DELIVERED stripe —
-            # capacity padding never crosses the link
-            from .readback import fetch_stream_bytes
-            deliver = np.nonzero(send)[0] if not force_all \
-                else np.arange(g.n_stripes)
-            last = int(deliver[-1])
+            lo = int(starts[deliver[0]])
+            hi = int(starts[deliver[-1]] + lens[deliver[-1]])
+            from .readback import fetch_stripe_bytes
             with _tracer.span("readback.copy", tl, lane=lane):
-                data = fetch_stream_bytes(out["data"],
-                                          int(starts[last] + lens[last]),
+                data = fetch_stripe_bytes(out["data"], lo, hi - lo,
                                           tl=tl, lane=lane)
+            starts -= lo              # offsets into the fetched range
         _tracer.record_span(tl, "encode.readback", rb_t0, lane=lane)
         if overflowed:
             self._handle_overflow(out)
-            return []
+            return None
         if idle:
-            return []                 # idle frame: fetched nothing at all
-        with _tracer.span("packetize", tl, lane=lane):
-            chunks: list[EncodedChunk] = []
-            for i in range(g.n_stripes):
-                if not (force_all or send[i]):
-                    continue
-                raw = data[starts[i]:starts[i] + lens[i]]
-                chunks.append(self._chunk(out, i, raw, bool(is_paint[i])))
-        return chunks
-
-    def finalize_stream(self, out: dict[str, Any], force_all: bool = False):
-        """Stripe-granular finalize (deep pipeline, ROADMAP 2): yields
-        each stripe's wire-ready chunk AS ITS BYTES LAND — per-stripe
-        device fetches (engine/readback.fetch_stripe_bytes) instead of
-        one frame-barrier prefix fetch, so the fanout ships the first
-        stripe while later stripes are still crossing the host link.
-        Byte-identical to :meth:`finalize` (same buffer, same slices;
-        tests pin it for both codecs)."""
-        g = self.grid
-        tl = _tracer.lookup(self.settings.display_id, out["frame_id"])
-        lane = f"slot{out['slot']}" if "slot" in out else None
-        rb_t0 = out.get("submitted_ns") or time.monotonic_ns()
-        overflowed, idle, force_all, lens, send, is_paint = \
-            self._sync_control(out, force_all, tl, lane)
-        _tracer.record_span(tl, "encode.readback", rb_t0, lane=lane)
-        if overflowed:
-            self._handle_overflow(out)
-            return
-        if idle:
-            return
-        from .readback import fetch_stripe_bytes
-        starts = np.concatenate([[0], np.cumsum(lens)])
-        for i in range(g.n_stripes):
-            if not (force_all or send[i]):
-                continue
-            with _tracer.span("encode.readback", tl, lane=lane), \
-                    _tracer.span("readback.copy", tl, lane=lane):
-                raw = fetch_stripe_bytes(out["data"], int(starts[i]),
-                                         int(lens[i]), tl=tl, lane=lane)
-            with _tracer.span("packetize", tl, lane=lane):
-                chunk = self._chunk(out, i, raw, bool(is_paint[i]))
-            yield chunk
+            return None               # idle frame: fetched nothing at all
+        return tl, lane, deliver, lambda i: self._chunk(
+            out, i, data[starts[i]:starts[i] + lens[i]], bool(is_paint[i]))
 
     def _sync_control(self, out: dict[str, Any], force_all: bool, tl=None,
                       lane=None):
-        """Control-array sync shared by finalize and finalize_stream —
+        """Control-array sync of :meth:`_readback` —
         the one device-sync point (absorbs the step's compute stall;
         ``readback.device_wait`` on the frame's timeline ``tl``) and the
         force-after-drop promotion. -> (overflowed, idle, force_all,

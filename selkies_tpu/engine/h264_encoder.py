@@ -674,8 +674,44 @@ class H264EncoderSession:
     def finalize(self, out: dict[str, Any], force_all: bool = False
                  ) -> list[EncodedChunk]:
         """``force_all`` is ignored — forced refreshes are an encode()-time
-        decision for this codec (idr parity lives on device)."""
+        decision for this codec (idr parity lives on device). One
+        ``packetize`` span for the frame."""
         del force_all
+        got = self._readback(out)
+        if got is None:
+            return []
+        tl, lane, deliver, chunk = got
+        with _tracer.span("packetize", tl, lane=lane):
+            return [chunk(i) for i in deliver]
+
+    def finalize_stream(self, out: dict[str, Any], force_all: bool = False):
+        """Stripe-granular finalize (deep pipeline, ROADMAP 2): the same
+        one fetch per frame as :meth:`finalize`, then yields each
+        stripe's access unit as it is assembled. Byte-identical to
+        :meth:`finalize`; chain-gating semantics are untouched (chunks
+        still carry is_idr per stripe and flow through the same relay
+        row gates)."""
+        del force_all
+        got = self._readback(out)
+        if got is None:
+            return
+        tl, lane, deliver, chunk = got
+        for i in deliver:
+            with _tracer.span("packetize", tl, lane=lane):
+                c = chunk(i)
+            yield c
+
+    def _readback(self, out: dict[str, Any]):
+        """The frame's readback, shared by finalize and finalize_stream:
+        the control sync, then ONE device fetch. Stock frames (IDR,
+        stock P) fetch the rows of the first to the last delivered
+        stripe (engine/readback.fetch_stripe_bytes): clean stripes
+        outside that range and the capacity padding never cross the host
+        link, and a round trip per stripe would cost more than its
+        bytes. Band frames fetch the band's prefix through the last band
+        row of a delivered stripe (clean rows never existed on device).
+        -> None when there is nothing to send (overflow, idle frame),
+        else (tl, lane, the delivered stripe indices, chunk(i))."""
         g = self.grid
         # ONE readback span per frame, from the slot's submit (in-flight
         # time IS readback time) through the ring's admission and queue
@@ -690,45 +726,41 @@ class H264EncoderSession:
         overflowed, idle, lens, send, intra = self._sync_control(out, tl,
                                                                  lane)
         band = out.get("band")
-        data = starts = None
+        data = None
         if not overflowed and not idle:
             starts = self._row_starts(out, lens)
             rps = g.rows_per_stripe
-            # minimal readback (engine/readback.py): fetch through
-            # the last DELIVERED stripe's rows — capacity padding
-            # and trailing unsent stripes never cross the host link.
-            # Band frames fetch through the last band row belonging to
-            # a sent stripe: clean rows never existed on device at all.
-            from .readback import fetch_stream_bytes
+            deliver = [int(i) for i in np.nonzero(send)[0]]
+            from .readback import fetch_stream_bytes, fetch_stripe_bytes
             if band is None:
-                last_row = (int(np.nonzero(send)[0][-1]) + 1) * rps - 1
-            else:
-                last_row = self._band_last_row(send, band)
-            if last_row is not None:
+                lo = int(starts[deliver[0] * rps])
+                last = (deliver[-1] + 1) * rps - 1
                 with _tracer.span("readback.copy", tl, lane=lane):
-                    data = fetch_stream_bytes(
-                        out["data"], int(starts[last_row] + lens[last_row]),
+                    data = fetch_stripe_bytes(
+                        out["data"], lo, int(starts[last] + lens[last]) - lo,
                         tl=tl, lane=lane)
+                starts = starts - lo      # offsets into the fetched range
+            else:
+                last = self._band_last_row(send, band)
+                if last is not None:
+                    with _tracer.span("readback.copy", tl, lane=lane):
+                        data = fetch_stream_bytes(
+                            out["data"], int(starts[last] + lens[last]),
+                            tl=tl, lane=lane)
         _tracer.record_span(tl, "encode.readback", rb_t0, lane=lane)
         if overflowed:
             self._handle_overflow(out)
-            return []
+            return None
         if idle:
-            return []                 # idle frame: fetched nothing at all
-        with _tracer.span("packetize", tl, lane=lane):
-            chunks: list[EncodedChunk] = []
-            for i in range(g.n_stripes):
-                if not send[i]:
-                    continue
-                rows = self._stripe_row_bytes(out, i, data, starts,
-                                              lens, band)
-                chunks.append(self._chunk(out, i, rows, intra))
-        return chunks
+            return None               # idle frame: fetched nothing at all
+        return tl, lane, deliver, lambda i: self._chunk(
+            out, i, self._stripe_row_bytes(out, i, data, starts, lens, band),
+            intra)
 
     def _band_last_row(self, send, band) -> Optional[int]:
-        """Band-frame fetch bound shared by finalize/finalize_stream:
-        the last BAND-LOCAL row belonging to a delivered stripe (None
-        when no band row is — clean rows never existed on device)."""
+        """Band-frame fetch bound of :meth:`_readback`: the last
+        BAND-LOCAL row belonging to a delivered stripe (None when no
+        band row is — clean rows never existed on device)."""
         row0, brows = band
         rps = self.grid.rows_per_stripe
         in_band = np.nonzero(np.repeat(send, rps)[row0:row0 + brows])[0]
@@ -736,10 +768,11 @@ class H264EncoderSession:
 
     def _stripe_row_bytes(self, out: dict[str, Any], i: int, data,
                           starts, lens, band) -> list:
-        """Stripe ``i``'s per-row slice RBSPs. Stock frames slice the
-        device buffer; band frames stitch device-encoded band rows
-        against host-built all-skip slices at the (byte-aligned) slice
-        seams — the partial-encode assembly."""
+        """Stripe ``i``'s per-row slice RBSPs (``starts``: each row's
+        offset in the fetched ``data``). Stock frames slice the fetched
+        range; band frames stitch device-encoded band rows against
+        host-built all-skip slices at the (byte-aligned) slice seams —
+        the partial-encode assembly."""
         g = self.grid
         rps = g.rows_per_stripe
         if band is None:
@@ -760,70 +793,6 @@ class H264EncoderSession:
                     (r % rps) * g.mb_w, g.mb_w, qp, int(fnum_used[i])))
         return rows
 
-    def finalize_stream(self, out: dict[str, Any], force_all: bool = False):
-        """Stripe-granular finalize (deep pipeline, ROADMAP 2): yields
-        each stripe's access unit AS ITS ROWS' BYTES LAND — per-stripe
-        device fetches instead of the frame-barrier prefix fetch.
-        Byte-identical to :meth:`finalize`; chain-gating semantics are
-        untouched (chunks still carry is_idr per stripe and flow through
-        the same relay row gates)."""
-        del force_all
-        g = self.grid
-        tl = _tracer.lookup(self.settings.display_id, out["frame_id"])
-        lane = f"slot{out['slot']}" if "slot" in out else None
-        rb_t0 = out.get("submitted_ns") or time.monotonic_ns()
-        overflowed, idle, lens, send, intra = self._sync_control(out, tl,
-                                                                 lane)
-        _tracer.record_span(tl, "encode.readback", rb_t0, lane=lane)
-        if overflowed:
-            self._handle_overflow(out)
-            return
-        if idle:
-            return
-        starts = self._row_starts(out, lens)
-        rps = g.rows_per_stripe
-        band = out.get("band")
-        if band is not None:
-            # band frames: the whole band is one small prefix fetch
-            # (clean rows never existed on device), then per-stripe
-            # stitching — stripe streaming degrades to a single fetch
-            lb = self._band_last_row(send, band)
-            data = None
-            if lb is not None:
-                from .readback import fetch_stream_bytes
-                with _tracer.span("encode.readback", tl, lane=lane), \
-                        _tracer.span("readback.copy", tl, lane=lane):
-                    data = fetch_stream_bytes(
-                        out["data"], int(starts[lb] + lens[lb]),
-                        tl=tl, lane=lane)
-            for i in range(g.n_stripes):
-                if not send[i]:
-                    continue
-                with _tracer.span("packetize", tl, lane=lane):
-                    rows = self._stripe_row_bytes(out, i, data, starts,
-                                                  lens, band)
-                    chunk = self._chunk(out, i, rows, intra)
-                yield chunk
-            return
-        from .readback import fetch_stripe_bytes
-        for i in range(g.n_stripes):
-            if not send[i]:
-                continue
-            r0, r1 = i * rps, (i + 1) * rps
-            with _tracer.span("encode.readback", tl, lane=lane), \
-                    _tracer.span("readback.copy", tl, lane=lane):
-                raw = fetch_stripe_bytes(
-                    out["data"], int(starts[r0]),
-                    int(starts[r1 - 1] + lens[r1 - 1] - starts[r0]),
-                    tl=tl, lane=lane)
-            with _tracer.span("packetize", tl, lane=lane):
-                base = int(starts[r0])
-                rows = [bytes(raw[starts[r] - base:
-                                  starts[r] - base + lens[r]])
-                        for r in range(r0, r1)]
-                chunk = self._chunk(out, i, rows, intra)
-            yield chunk
-
     def _row_starts(self, out: dict[str, Any], lens: np.ndarray
                     ) -> np.ndarray:
         """Absolute byte offset of each MB row inside ``out['data']``.
@@ -833,7 +802,7 @@ class H264EncoderSession:
         return np.concatenate([[0], np.cumsum(lens[:-1])]).astype(np.int64)
 
     def _sync_control(self, out: dict[str, Any], tl=None, lane=None):
-        """Control-array sync shared by finalize and finalize_stream —
+        """Control-array sync of :meth:`_readback` —
         the one device-sync point (``readback.device_wait`` on the
         frame's timeline ``tl``). -> (overflowed, idle, lens, send,
         intra)."""
@@ -957,9 +926,10 @@ class StripeShardedH264Session(H264EncoderSession):
     Same lifecycle/finalize contract as :class:`H264EncoderSession` and
     BYTE-IDENTICAL chunk payloads (tests/test_stripes.py): sharding is a
     pure distribution axis. Each device's rows land in that shard's
-    region of the output buffer, so ``finalize_stream`` ships a shard's
-    stripes as soon as that shard's readback lands — composing with the
-    PR-10 PipelineRing and stripe-streaming fetch unchanged."""
+    region of the output buffer; ``_row_starts`` gives their absolute
+    offsets, so the finalizer's one range fetch per frame (which then
+    carries the capacity padding between shard regions) slices them
+    exactly."""
 
     def __init__(self, settings: CaptureSettings, devices=None):
         g = plan_h264_grid(settings)

@@ -11,13 +11,18 @@ fits in 32-64 KB, and an idle frame (no stripes sent) now fetches
 nothing at all. Byte-identical to fetching everything — the slice is a
 prefix; tests cover both encoders bit-exactly.
 
-Stripe-granular path (ROADMAP 2): :func:`fetch_stripe_bytes` slices an
-ARBITRARY byte range on device (``dynamic_slice`` with a bucketed
-static length, so the jit cache stays one-per-bucket) — the deep
-pipeline's streaming finalize ships each stripe's bytes as they land
-instead of waiting on the frame barrier. Stripe fetches use a smaller
-bucket floor than the whole-frame prefix: a stripe is latency-bound,
-not bandwidth-bound.
+Range path (ROADMAP 2): :func:`fetch_stripe_bytes` slices an ARBITRARY
+byte range on device (``dynamic_slice`` with a bucketed static length,
+so the jit cache stays one-per-bucket). The single-seat sessions'
+finalize makes ONE such fetch per frame, over the delivered stripes
+from the first to the last (an H.264 band frame: one prefix fetch of
+the band), cuts the stripes from the host copy and still yields them
+one at a time: each device round trip costs far more than the bytes it
+carries, so a fetch per stripe would set the frame's pace. The range
+leaves out the clean stripes before the first delivered one, which a
+prefix fetch would carry. Range fetches use a smaller bucket floor
+than the whole-frame prefix: a damage-gated range can be one small
+stripe.
 
 Both fetch paths carry the ``readback.fetch`` fault point
 (``slow``/``error``): an injected mid-pipeline readback death exercises
@@ -25,10 +30,10 @@ the ring-drain recovery path (``bench.py --chaos``).
 
 Given the frame's trace timeline (keyword ``tl``, tracer on), a fetch
 counts ``readback.bytes`` (what the transfer moves: the bucket, or the
-whole buffer), and waits for the bucketed slice on its own before the
-transfer completes (``readback.slice_wait`` on ``<lane>.wait``): the
-slice is enqueued on the device stream and can sit behind the next
-frame's step.
+whole buffer) and ``readback.fetches`` (one per transfer), and waits
+for the bucketed slice on its own before the transfer completes
+(``readback.slice_wait`` on ``<lane>.wait``): the slice is enqueued on
+the device stream and can sit behind the next frame's step.
 With the tracer off nothing is synced that the transfer would not sync.
 """
 
@@ -44,7 +49,7 @@ from ..trace import tracer as _tracer
 #: smallest whole-frame fetch; below this the dispatch RTT dominates
 MIN_BUCKET = 32768
 
-#: smallest per-stripe fetch (stripe streaming is latency-bound)
+#: smallest range fetch (a damage-gated range can be one small stripe)
 MIN_STRIPE_BUCKET = 4096
 
 
@@ -95,15 +100,16 @@ def bucket_for(total: int, floor: int = MIN_BUCKET) -> int:
 def _transfer(arr, tl, lane, extent: int | None = None,
               sync: bool = True) -> np.ndarray:
     """``np.asarray(arr)``: the device-to-host copy. With ``tl`` traced:
-    counted (``extent``, the last-axis length that crosses, default all
-    of it, per leading index) and, for a device slice (``sync``), its
-    wait for the device apart from the copy."""
+    counted (one fetch of ``extent`` bytes, the last-axis length that
+    crosses, default all of it, per leading index) and, for a device
+    slice (``sync``), its wait for the device apart from the copy."""
     if tl is None or not _tracer.enabled:
         return np.asarray(arr)
     width = int(arr.shape[-1])
     rows = int(arr.size) // width if width else 0
     _tracer.count(tl, "readback.bytes",
                   rows * (width if extent is None else extent))
+    _tracer.count(tl, "readback.fetches", 1)
     if sync:
         # the copy is queued behind the slice first, as np.asarray alone
         # would: the wait below then adds no host round trip to it
@@ -135,7 +141,7 @@ def fetch_stream_bytes(data_dev, total: int, *, tl=None,
 def fetch_stripe_bytes(data_dev, start: int, length: int, *, tl=None,
                        lane=None) -> np.ndarray:
     """Fetch ``length`` bytes at ``start`` (along the last axis) — the
-    stripe-streaming fetch. Byte-identical to the same range of a
+    finalizers' one fetch per frame. Byte-identical to the same range of a
     whole-prefix fetch; the bucketed device slice may over-fetch up to
     one bucket, never under. ``tl``/``lane`` as for
     :func:`fetch_stream_bytes`."""

@@ -52,9 +52,10 @@ class CaptureSettings:
     # relay backpressure clamp and the degradation ladder's rung-0
     # "pipeline" action can force 1 at runtime without a session rebuild.
     pipeline_depth: int = 2
-    # ship each stripe's bytes as its readback lands (per-stripe fetch,
-    # engine/readback.py) instead of waiting on the frame barrier —
-    # client first-stripe receive decouples from frame-complete
+    # ship each stripe as soon as it is cut from the frame's one fetch
+    # and wrapped (engine/readback.py) instead of after the whole frame
+    # is wrapped — client first-stripe receive decouples from
+    # frame-complete
     stripe_streaming: bool = True
     # h264 inter motion search (scroll/pan candidates; 0 vrange disables).
     # Dense vertical offsets up to vrange px; power-of-two horizontal pans

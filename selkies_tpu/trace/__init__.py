@@ -26,8 +26,8 @@ frame stages ``capture``, ``convert``, ``encode.dispatch``,
 span ``readback.copy`` nested in ``encode.readback``, the wait spans
 (on ``<lane>.wait``) ``encode.probe_sync``, ``pipeline.admit``,
 ``pipeline.queue``, ``readback.device_wait``, ``readback.slice_wait``,
-``fanout.hop`` and ``relay.queue`` — plus the ``ack`` instant. Counter:
-``readback.bytes``.
+``fanout.hop`` and ``relay.queue`` — plus the ``ack`` instant. Counters:
+``readback.bytes``, ``readback.fetches``.
 """
 
 from .core import FrameTracer, FrameTimeline, tracer  # noqa: F401

@@ -188,13 +188,13 @@ def test_h264_pipelined_byte_identical_to_serial(stream):
     assert serial == piped
 
 
-def _session(codec):
+def _session(codec, **over):
     from selkies_tpu.engine.encoder import JpegEncoderSession
     from selkies_tpu.engine.h264_encoder import H264EncoderSession
     if codec == "jpeg":
-        return JpegEncoderSession(CaptureSettings(**SMALL))
+        return JpegEncoderSession(CaptureSettings(**dict(SMALL, **over)))
     return H264EncoderSession(CaptureSettings(**dict(
-        SMALL, output_mode="h264", video_crf=28)))
+        SMALL, output_mode="h264", video_crf=28, **over)))
 
 
 def _run_traced(sess, frames, stream):
@@ -281,15 +281,18 @@ def test_traced_frames_hold_their_waits_nested(codec, stream, monkeypatch):
             copies = named("readback.copy")
             waits = named("readback.slice_wait")
             if not any(c[0] == out["frame_id"] for c in traced):
-                assert not copies and "readback.bytes" not in d["counts"]
+                assert not copies
+                assert "readback.bytes" not in d["counts"]
+                assert "readback.fetches" not in d["counts"]
                 continue           # nothing damaged: nothing fetched
-            assert copies and len(waits) == len(copies)
+            # one fetch per frame: one copy, its slice wait inside it
+            copy, = copies
+            wait, = waits
             assert d["counts"]["readback.bytes"] > 0
-            for c in copies:
-                assert c["lane"] == lane and "cpu_ns" in c
-                assert any(_inside(c, r) for r in named("encode.readback"))
-                assert any(_inside(w, c) for w in waits)
-            assert {w["lane"] for w in waits} == {lane + ".wait"}
+            assert d["counts"]["readback.fetches"] == 1
+            assert copy["lane"] == lane and "cpu_ns" in copy
+            assert _inside(copy, env) and _inside(wait, copy)
+            assert wait["lane"] == lane + ".wait"
             if codec == "h264" and not out.get("intra"):
                 probe, = named("encode.probe_sync")
                 dispatch, = named("encode.dispatch")
@@ -297,6 +300,62 @@ def test_traced_frames_hold_their_waits_nested(codec, stream, monkeypatch):
                 assert _inside(probe, dispatch)
     finally:
         tracer.clear()
+
+
+#: delivered-stripe masks over a 4-stripe frame
+SEND_MASKS = {"all": [1, 1, 1, 1], "first-and-last": [1, 0, 0, 1],
+              "one-middle": [0, 0, 1, 0], "none": [0, 0, 0, 0]}
+
+
+def _per_stripe_payloads(sess, out):
+    """What a fetch per delivered stripe gives: each stripe cut from a
+    host copy of the whole stream buffer, wrapped by the session."""
+    full = np.asarray(out["data"])
+    lens = np.asarray(out["lens"])
+    send = np.asarray(out["send"])
+    want = []
+    for i in np.nonzero(send)[0]:
+        if "intra" in out:                  # H.264: per-row slices
+            starts = sess._row_starts(out, lens)
+            rps = sess.grid.rows_per_stripe
+            rows = [bytes(full[starts[r]:starts[r] + lens[r]])
+                    for r in range(i * rps, (i + 1) * rps)]
+            chunk = sess._chunk(out, int(i), rows, out["intra"])
+        else:
+            start = int(np.sum(lens[:i]))
+            chunk = sess._chunk(out, int(i), full[start:start + lens[i]],
+                                bool(np.asarray(out["is_paint"])[i]))
+        want.append((chunk.stripe_y, chunk.payload))
+    return want
+
+
+@pytest.mark.parametrize("mask", list(SEND_MASKS))
+@pytest.mark.parametrize("codec", ["jpeg", "h264"])
+def test_one_device_fetch_per_frame_over_the_delivered_stripes(
+        codec, mask, monkeypatch):
+    """Whatever stripes a frame delivers, ``finalize_stream`` and
+    ``finalize`` give the bytes a fetch per stripe gave, from ONE device
+    fetch per frame — none for an idle frame."""
+    from selkies_tpu.engine import readback
+    from selkies_tpu.engine.sources import SyntheticSource
+    monkeypatch.setattr(readback, "_on_host", lambda arr: False)
+    fetches = []
+    for name in ("fetch_stream_bytes", "fetch_stripe_bytes"):
+        def counted(*a, _real=getattr(readback, name), **k):
+            fetches.append(a[1:])
+            return _real(*a, **k)
+        monkeypatch.setattr(readback, name, counted)
+    sess = _session(codec, capture_height=128)
+    assert sess.grid.n_stripes == len(SEND_MASKS[mask])
+    out = sess.encode(SyntheticSource(64, 128).get_frame(0), force=True)
+    out["send"] = np.array(SEND_MASKS[mask], bool)
+    want = _per_stripe_payloads(sess, out)
+    streamed = [(c.stripe_y, c.payload) for c in sess.finalize_stream(out)]
+    assert len(fetches) == (0 if mask == "none" else 1)
+    batch = [(c.stripe_y, c.payload) for c in sess.finalize(out)]
+    assert len(fetches) == (0 if mask == "none" else 2)
+    assert streamed == batch == want
+    assert len(want) == sum(SEND_MASKS[mask])
 
 
 @pytest.mark.parametrize("codec", ["jpeg", "h264"])

@@ -85,7 +85,8 @@ def _traced_timeline():
 
 def test_traced_device_fetch_counts_the_buckets(monkeypatch):
     """On the device path (forced here: the CPU backend is host memory)
-    ``readback.bytes`` is the sum of the buckets that crossed, and each
+    ``readback.bytes`` is the sum of the buckets that crossed,
+    ``readback.fetches`` the number of transfers, and each
     bucketed slice's wait is a ``readback.slice_wait`` on the lane's
     wait track."""
     import jax.numpy as jnp
@@ -112,7 +113,8 @@ def test_traced_device_fetch_counts_the_buckets(monkeypatch):
         buckets = [bucket_for(length) if start is None
                    else bucket_for(length, MIN_STRIPE_BUCKET)
                    for start, length in asks]
-        assert tl.counts == {"readback.bytes": sum(buckets)}
+        assert tl.counts == {"readback.bytes": sum(buckets),
+                             "readback.fetches": len(asks)}
         waits = [s for s in tl.spans if s[0] == "readback.slice_wait"]
         assert len(waits) == len(asks)
         assert {s[1] for s in waits} == {"slot1.wait"}
@@ -130,7 +132,7 @@ def test_traced_host_fetch_counts_what_it_copies():
     try:
         got = fetch_stripe_bytes(jnp.asarray(full), 100, 50, tl=tl)
         assert np.array_equal(got, full[:, 100:150])
-        assert tl.counts == {"readback.bytes": 100}
+        assert tl.counts == {"readback.bytes": 100, "readback.fetches": 1}
         assert tl.spans == []
     finally:
         tracer.disable()
