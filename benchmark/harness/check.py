@@ -4,12 +4,17 @@ Once the window has closed and the server is down, the frames the client
 received are decoded by the reference decoders (``reference/decoders``),
 composed onto a canvas as a browser composes stripes, and compared with
 the harness's own source frame for the content each frame carries (the
-tick log). Both codecs are compared in BT.601 full-range YCbCr 4:2:0,
-the form both decoders give: luma by its worst 16x16 block, each chroma
-plane by its worst 8x8 block (one macroblock), and each plane by the
-frame's mean. A frame that shows stale content, a dropped or misplaced
-stripe, a decoder-encoder mismatch, lost colour or coarser quantisation
-moves these far more than coding noise at the configured quality does.
+tick log). Both codecs are compared in BT.601 full-range YCbCr at the
+chroma sampling the configuration promises (``sampling``: 4:2:0 unless
+it says ``"chroma": "444"``), the form both decoders give: luma by its
+worst 16x16 block, each chroma plane by its worst block of one
+macroblock's chroma (8x8 at 4:2:0, 16x16 at 4:4:4), and each plane by
+the frame's mean. Under a 4:4:4 promise a picture of 4:2:0 does not
+decode; an H.264 4:4:4 picture under a 4:2:0 promise does not either (a
+4:4:4 JPEG is averaged to 4:2:0 there, as before). A frame that shows
+stale content, a dropped or misplaced stripe, a decoder-encoder
+mismatch, lost colour or coarser quantisation moves these far more than
+coding noise at the configured quality does.
 
 What is compared: a seeded sample of the frames sent in the window, plus
 the largest of them and every IDR among them. The decoders run over every
@@ -27,6 +32,17 @@ from reference import decoders
 
 #: frames compared besides the largest and the IDRs
 SAMPLE = 40
+
+
+def sampling(config: dict) -> str:
+    """The chroma sampling a configuration promises: its ``chroma``
+    key, ``"420"`` where it has none. The check holds the stream to it,
+    never to what the stream says of itself."""
+    chroma = config.get("chroma", "420")
+    if chroma not in decoders.SAMPLINGS:
+        raise ValueError(f"no chroma sampling {chroma!r}; the check knows "
+                         f"{decoders.SAMPLINGS}")
+    return chroma
 
 
 def _sample(window: list, frames: dict, seed: int) -> set:
@@ -52,10 +68,10 @@ def _start_fid(codec: str, frames: dict, first: int) -> int:
 
 
 def compare(codec: str, frames: dict, window: list, keys: dict, desktop,
-            seed: int) -> dict:
+            seed: int, chroma: str) -> dict:
     """-> numbers: worst_luma_mse, worst_chroma_mse, frame_luma_mse,
     frame_chroma_mse (each the largest over the compared frames),
-    undecodable, compared."""
+    undecodable, compared; everything at the ``chroma`` sampling."""
     pick = _sample(window, frames, seed)
     worst = dict.fromkeys(MSE, 0.0)
     if not window:
@@ -73,9 +89,9 @@ def compare(codec: str, frames: dict, window: list, keys: dict, desktop,
         for fid in sorted(f for f in frames if f < first):
             before.update(frames[fid].got)
         for y, s in before.items():
-            planes = decoders.jpeg_planes(s.payload)
+            planes = decoders.jpeg_planes(s.payload, chroma)
             if planes is not None:
-                _paste(canvas, y, planes)
+                _paste(canvas, y, planes, chroma)
     undecodable = 0
     compared = 0
     try:
@@ -85,25 +101,25 @@ def compare(codec: str, frames: dict, window: list, keys: dict, desktop,
                 if codec == "h264":
                     dec = streams.get(y)
                     if dec is None:
-                        dec = streams[y] = decoders.H264Stream(s.width,
-                                                               s.height)
+                        dec = streams[y] = decoders.H264Stream(
+                            s.width, s.height, chroma)
                     planes = dec.decode(s.payload)
                 else:
-                    planes = decoders.jpeg_planes(s.payload)
+                    planes = decoders.jpeg_planes(s.payload, chroma)
                 if planes is None:
                     bad = True
                     continue
-                _paste(canvas, y, planes)
+                _paste(canvas, y, planes, chroma)
             if fid in in_window and bad:
                 undecodable += 1
             if fid in pick and canvas[0] is not None:
-                want = decoders.planes(desktop.render(keys[fid]))
+                want = decoders.planes(desktop.render(keys[fid]), chroma)
                 got = [c[:w.shape[0], :w.shape[1]]
                        for c, w in zip(canvas, want)]
                 if any(g.shape != w.shape for g, w in zip(got, want)):
                     worst = dict.fromkeys(MSE, float("inf"))
                 else:
-                    _worse(worst, got, want)
+                    _worse(worst, got, want, chroma)
                 compared += 1
     finally:
         for dec in streams.values():
@@ -118,10 +134,11 @@ MSE = ("worst_luma_mse", "worst_chroma_mse", "frame_luma_mse",
        "frame_chroma_mse")
 
 
-def _worse(worst: dict, got: list, want: list) -> None:
+def _worse(worst: dict, got: list, want: list, chroma: str) -> None:
     d = [(g - w) ** 2 for g, w in zip(got, want)]
+    mb_chroma = 8 if chroma == "420" else 16     # one macroblock's chroma
     now = {"worst_luma_mse": decoders.worst_block_mse(got[0], want[0], 16),
-           "worst_chroma_mse": max(decoders.worst_block_mse(g, w, 8)
+           "worst_chroma_mse": max(decoders.worst_block_mse(g, w, mb_chroma)
                                    for g, w in zip(got[1:], want[1:])),
            "frame_luma_mse": float(d[0].mean()),
            "frame_chroma_mse": float(max(d[1].mean(), d[2].mean()))}
@@ -129,11 +146,11 @@ def _worse(worst: dict, got: list, want: list) -> None:
         worst[k] = max(worst[k], v)
 
 
-def _paste(canvas: list, y: int, planes: tuple) -> None:
-    """Paste a stripe's (Y, Cb, Cr) at row ``y`` (chroma at ``y / 2``),
-    growing the canvas as a browser's does."""
+def _paste(canvas: list, y: int, planes: tuple, chroma: str) -> None:
+    """Paste a stripe's (Y, Cb, Cr) at row ``y`` (at 4:2:0 the chroma at
+    ``y / 2``), growing the canvas as a browser's does."""
     for i, plane in enumerate(planes):
-        at = y if i == 0 else y // 2
+        at = y if i == 0 or chroma == "444" else y // 2
         h = at + plane.shape[0]
         c = canvas[i]
         if c is None or c.shape[0] < h:

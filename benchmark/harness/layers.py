@@ -15,6 +15,8 @@ from pathlib import Path
 
 import numpy as np
 
+from harness import check
+
 BENCH = Path(__file__).resolve().parent.parent
 
 
@@ -144,18 +146,20 @@ def dirty_mb_rows(ctx: Context, fid: int, coded_h: int) -> int:
 def roofline_pct(ctx: Context) -> float | None:
     """Least time for the bytes the traced frames' work needs, at the
     device's peak bandwidth, over the device's busy time in the traced
-    span: bandwidth-bound by construction (see counts/)."""
+    span: bandwidth-bound by construction (see counts/), at the
+    configuration's chroma sampling."""
     t = ctx.trace
     frames = traced_frames(ctx)
     if not t or not t["busy_ns"] or not frames:
         return None
     counts = load_module(BENCH / "counts" / f"{ctx.run.config['codec']}.py")
     width, coded_h = ctx.run.config["coded"]
+    chroma = check.sampling(ctx.run.config)
     total = 0
     for f in frames:
         total += counts.bytes_needed(
             width, coded_h, intra=f.idr,
             dirty_rows=dirty_mb_rows(ctx, f.fid, coded_h),
-            payload_bytes=f.nbytes)
+            payload_bytes=f.nbytes, chroma=chroma)
     least_s = total / (ctx.peaks["hbm_bytes_per_s"])
     return 100.0 * least_s / (t["busy_ns"] / 1e9)

@@ -17,7 +17,22 @@ import time
 import numpy as np
 
 #: the check's controls a source can play (``TrafficSource.mode``)
-MODES = (None, "stale", "grey")
+MODES = (None, "stale", "grey", "halfchroma")
+
+
+def half_chroma(rgb: np.ndarray) -> np.ndarray:
+    """An RGB frame taken to BT.601 full-range YCbCr, Cb and Cr averaged
+    over 2x2 pixels and repeated back to full size, and returned as RGB
+    (rounded, clipped): the frame's colour at 4:2:0."""
+    from reference.decoders import chroma_planes, half
+    h, w = rgb.shape[:2]
+    r, g, b = (rgb[..., i].astype(np.float64) for i in range(3))
+    y = 0.299 * r + 0.587 * g + 0.114 * b
+    cb, cr = (np.repeat(np.repeat(half(c), 2, axis=0), 2, axis=1)[:h, :w]
+              - 128.0 for c in chroma_planes(rgb))
+    out = np.stack([y + 1.402 * cr, y - 0.344136 * cb - 0.714136 * cr,
+                    y + 1.772 * cb], axis=-1)
+    return np.clip(np.round(out), 0, 255).astype(np.uint8)
 
 
 class TickLog:
@@ -39,9 +54,11 @@ class TrafficSource:
     ``stale``: each frame shows the content that was on the desktop one
     refresh before its logged content appeared, the least stale a frame
     can be; ``grey``: each frame shows its content's BT.601 luma in all
-    three channels, as an encoder that drops the colour would send it.
-    ``switch`` moves to another desktop and mode (a new phase) between
-    two ticks."""
+    three channels, as an encoder that drops the colour would send it;
+    ``halfchroma``: each frame shows its content with Cb and Cr averaged
+    over 2x2 pixels (``half_chroma``), the best a path that codes 4:2:0
+    colour inside a 4:4:4 stream can show. ``switch`` moves to another
+    desktop and mode (a new phase) between two ticks."""
 
     def __init__(self, desktop, log: TickLog, mode: str | None = None):
         import jax
@@ -70,6 +87,8 @@ class TrafficSource:
             from reference.decoders import luma
             frame = np.repeat(luma(frame).astype(np.uint8)[..., None], 3,
                               axis=2)
+        elif self._mode == "halfchroma":
+            frame = half_chroma(frame)
         return frame
 
     def get_frame(self, tick: int):

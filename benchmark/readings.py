@@ -7,7 +7,8 @@ One process boots the cell's server once, warms up as a run does, and
 then measures one window of ``--seconds`` per phase, at the cell's own
 load: a phase is ``<seed>`` (the program as configured, on that seed's
 traffic) or ``<mode>:<seed>`` with a mode of the source's
-(``harness.source``: ``stale``, ``grey``). ``--fidelity-control`` runs
+(``harness.source``: ``stale``, ``grey``, and for a configuration that
+promises 4:4:4 ``halfchroma``). ``--fidelity-control`` runs
 the program at the configuration's ``fidelity_control`` flags instead (a
 coarser quality). Once the server is down, each
 phase's window is checked as a run's is, and one JSON line per phase
@@ -37,11 +38,16 @@ def measure(cell: dict, config: dict, scene: dict, seconds: float,
 
     if flags:
         config = {**config, "flags": {**config["flags"], **flags}}
+    chroma = check.sampling(config)
     w, h = (int(config["flags"][k]) for k in ("initial_width",
                                               "initial_height"))
     phases = []
     for spec in specs:
         mode, _, seed = str(spec).rpartition(":")
+        if mode == "halfchroma" and chroma != "444":
+            raise ValueError("halfchroma is a control of a 4:4:4 "
+                             f"configuration; {config['name']} promises "
+                             f"{chroma}")
         phases.append(runner.Phase(Desktop(scene, w, h, int(seed)),
                                    int(seed), mode or None))
     run = runner.Run(cell=cell, config=config, seed=phases[0].seed,
@@ -59,7 +65,7 @@ def measure(cell: dict, config: dict, scene: dict, seconds: float,
         window = [f for f in runner.window_frames(run, frames, ph.w0, ph.w1)
                   if run.phase.get(f) == k]
         numbers = check.compare(config["codec"], frames, window, run.keys,
-                                ph.desktop, ph.seed)
+                                ph.desktop, ph.seed, chroma)
         numbers["never_arrived"] = sum(
             1 for f in window if frames[f].whole_at() is None)
         correct, _ = check.verdict(numbers, limits)

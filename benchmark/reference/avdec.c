@@ -1,7 +1,8 @@
 /* The check's H.264 decoder: libavcodec, which shares nothing with the
  * encoder under test. One decoder per stream; each access unit in, the
- * decoded YUV 4:2:0 planes out, in decode order (the streams carry no
- * B frames, so output order is decode order under LOW_DELAY).
+ * decoded YUV planes out, 4:2:0 or 4:4:4 as the caller expects, in
+ * decode order (the streams carry no B frames, so output order is decode
+ * order under LOW_DELAY).
  *
  * Built at run time by reference/decoders.py:
  *   gcc -O2 -shared -fPIC -o avdec.so avdec.c -lavcodec -lavutil
@@ -46,10 +47,12 @@ void *bd_open(void)
 
 /* Feed one access unit; 0 with a picture in the buffers, 1 when the
  * decoder wants more data, < 0 on an error. w and h are the buffers'
- * luma size: a picture of another size is -1001, of another format
- * -1002. A damaged stream fails in avcodec itself (AV_EF_EXPLODE). */
+ * luma size; c444 says which sampling the stream promises: 0 for 4:2:0
+ * (Cb and Cr buffers of w/2 x h/2), 1 for 4:4:4 (w x h). A picture of
+ * another size is -1001, of another format or sampling -1002. A damaged
+ * stream fails in avcodec itself (AV_EF_EXPLODE). */
 int bd_decode(void *h, const uint8_t *data, int size, int w, int hgt,
-              uint8_t *y, uint8_t *u, uint8_t *v)
+              int c444, uint8_t *y, uint8_t *u, uint8_t *v)
 {
     Dec *d = (Dec *)h;
     int ret = av_new_packet(d->pkt, size);
@@ -65,11 +68,12 @@ int bd_decode(void *h, const uint8_t *data, int size, int w, int hgt,
         return 1;
     if (ret < 0)
         return ret;
+    int fmt = d->frame->format;
     int bad = 0;
     if (d->frame->width != w || d->frame->height != hgt)
         bad = -1001;
-    else if (d->frame->format != AV_PIX_FMT_YUV420P
-             && d->frame->format != AV_PIX_FMT_YUVJ420P)
+    else if (c444 ? fmt != AV_PIX_FMT_YUV444P && fmt != AV_PIX_FMT_YUVJ444P
+                  : fmt != AV_PIX_FMT_YUV420P && fmt != AV_PIX_FMT_YUVJ420P)
         bad = -1002;
     if (bad) {
         av_frame_unref(d->frame);
@@ -78,11 +82,12 @@ int bd_decode(void *h, const uint8_t *data, int size, int w, int hgt,
     for (int r = 0; r < hgt; r++)
         memcpy(y + (size_t)r * w,
                d->frame->data[0] + (size_t)r * d->frame->linesize[0], w);
-    for (int r = 0; r < hgt / 2; r++) {
-        memcpy(u + (size_t)r * (w / 2),
-               d->frame->data[1] + (size_t)r * d->frame->linesize[1], w / 2);
-        memcpy(v + (size_t)r * (w / 2),
-               d->frame->data[2] + (size_t)r * d->frame->linesize[2], w / 2);
+    int cw = c444 ? w : w / 2, ch = c444 ? hgt : hgt / 2;
+    for (int r = 0; r < ch; r++) {
+        memcpy(u + (size_t)r * cw,
+               d->frame->data[1] + (size_t)r * d->frame->linesize[1], cw);
+        memcpy(v + (size_t)r * cw,
+               d->frame->data[2] + (size_t)r * d->frame->linesize[2], cw);
     }
     av_frame_unref(d->frame);
     return 0;

@@ -4,9 +4,11 @@ Nothing here imports the program. H.264 access units go to libavcodec
 through ``avdec.c`` (built on first use into the benchmark's own cache
 directory); JPEG stripes go to PIL. The source's RGB frames are turned
 into what a conforming decoder should show with plain numpy: BT.601
-full-range YCbCr, as the encoder's VUI and JFIF signal, with the chroma
-planes averaged over 2x2 pixels to 4:2:0. Decoded pictures are compared
-in the same form: three planes, Y at full size, Cb and Cr at half.
+full-range YCbCr, as the encoder's VUI and JFIF signal, at the chroma
+sampling the configuration promises: ``"420"``, the chroma planes
+averaged over 2x2 pixels, or ``"444"``, every plane at full size.
+Decoded pictures are compared in the same form, and a picture of the
+other sampling does not decode.
 """
 
 from __future__ import annotations
@@ -21,6 +23,8 @@ import numpy as np
 
 HERE = Path(__file__).resolve().parent
 BUILD = HERE.parent / ".cache" / "build"
+#: the chroma samplings a configuration can promise
+SAMPLINGS = ("420", "444")
 
 
 def _library() -> ctypes.CDLL:
@@ -39,7 +43,8 @@ def _library() -> ctypes.CDLL:
     buf = ctypes.POINTER(ctypes.c_uint8)
     lib.bd_decode.restype = ctypes.c_int
     lib.bd_decode.argtypes = [ctypes.c_void_p, ctypes.c_char_p, ctypes.c_int,
-                              ctypes.c_int, ctypes.c_int, buf, buf, buf]
+                              ctypes.c_int, ctypes.c_int, ctypes.c_int,
+                              buf, buf, buf]
     lib.bd_close.restype = None
     lib.bd_close.argtypes = [ctypes.c_void_p]
     return lib
@@ -48,24 +53,28 @@ def _library() -> ctypes.CDLL:
 class H264Stream:
     """One H.264 stream (one stripe position), decoded access unit by
     access unit. ``decode`` returns the picture's (Y, Cb, Cr) planes, or
-    None when the unit did not decode to a 4:2:0 picture of the stream's
-    size."""
+    None when the unit did not decode to a picture of the stream's size
+    at the ``chroma`` sampling."""
 
-    def __init__(self, width: int, height: int):
+    def __init__(self, width: int, height: int, chroma: str):
+        if chroma not in SAMPLINGS:
+            raise ValueError(f"no chroma sampling {chroma!r}")
         self._lib = _library()
         self.w, self.h = width, height
+        self._c444 = int(chroma == "444")
         self._h = self._lib.bd_open()
         if not self._h:
             raise RuntimeError("libavcodec has no H.264 decoder")
+        cw, ch = (width, height) if self._c444 else (width // 2, height // 2)
         self._y = np.empty((height, width), np.uint8)
-        self._u = np.empty((height // 2, width // 2), np.uint8)
-        self._v = np.empty((height // 2, width // 2), np.uint8)
+        self._u = np.empty((ch, cw), np.uint8)
+        self._v = np.empty((ch, cw), np.uint8)
 
     def decode(self, access_unit: bytes) -> tuple | None:
         ptr = ctypes.POINTER(ctypes.c_uint8)
         rc = self._lib.bd_decode(
             self._h, access_unit, len(access_unit), self.w, self.h,
-            self._y.ctypes.data_as(ptr), self._u.ctypes.data_as(ptr),
+            self._c444, self._y.ctypes.data_as(ptr), self._u.ctypes.data_as(ptr),
             self._v.ctypes.data_as(ptr))
         if rc != 0:
             return None
@@ -77,14 +86,18 @@ class H264Stream:
             self._h = None
 
 
-def jpeg_planes(data: bytes) -> tuple | None:
+def jpeg_planes(data: bytes, chroma: str) -> tuple | None:
     """A JPEG stripe decoded by PIL straight to YCbCr (libjpeg with no
-    colour conversion), as (Y, Cb, Cr) with the chroma averaged to 4:2:0,
-    or None if it does not decode. A greyscale JPEG decodes to Cb = Cr =
-    128."""
-    from PIL import Image
+    colour conversion), as (Y, Cb, Cr), or None if it does not decode.
+    At ``"420"`` the chroma is averaged to 4:2:0 whatever the JPEG's own
+    sampling, and a greyscale JPEG decodes to Cb = Cr = 128. At
+    ``"444"`` the chroma stays at full size, and a JPEG whose components
+    are not all sampled 1x1 (4:4:4) does not decode."""
+    from PIL import Image, JpegImagePlugin
     try:
         img = Image.open(io.BytesIO(data))
+        if chroma == "444" and JpegImagePlugin.get_sampling(img) != 0:
+            return None
         img.draft("YCbCr", img.size)
         img.load()
     except (OSError, SyntaxError, ValueError):
@@ -92,6 +105,8 @@ def jpeg_planes(data: bytes) -> tuple | None:
     if img.mode != "YCbCr":
         img = img.convert("YCbCr")
     a = np.asarray(img, np.float64)
+    if chroma == "444":
+        return a[..., 0], a[..., 1], a[..., 2]
     return a[..., 0], half(a[..., 1]), half(a[..., 2])
 
 
@@ -109,13 +124,22 @@ def luma(rgb: np.ndarray) -> np.ndarray:
     return np.clip(np.round(0.299 * r + 0.587 * g + 0.114 * b), 0, 255)
 
 
-def planes(rgb: np.ndarray) -> tuple:
-    """What a conforming decoder should show for an RGB frame: BT.601
-    full-range (Y, Cb, Cr), Y rounded, Cb and Cr averaged to 4:2:0."""
+def chroma_planes(rgb: np.ndarray) -> tuple:
+    """BT.601 full-range Cb and Cr of an RGB frame, at full size."""
     r, g, b = (rgb[..., i].astype(np.float64) for i in range(3))
     cb = 128.0 - 0.168736 * r - 0.331264 * g + 0.5 * b
     cr = 128.0 + 0.5 * r - 0.418688 * g - 0.081312 * b
-    return luma(rgb), half(cb), half(cr)
+    return cb, cr
+
+
+def planes(rgb: np.ndarray, chroma: str) -> tuple:
+    """What a conforming decoder should show for an RGB frame: BT.601
+    full-range (Y, Cb, Cr), Y rounded, Cb and Cr averaged to 4:2:0 at
+    ``"420"`` and at full size at ``"444"``."""
+    cb, cr = chroma_planes(rgb)
+    if chroma == "420":
+        cb, cr = half(cb), half(cr)
+    return luma(rgb), cb, cr
 
 
 def worst_block_mse(got: np.ndarray, want: np.ndarray, block: int = 16

@@ -96,7 +96,7 @@ def execute(bench: dict, cell: dict, config: dict, scene: dict, seed: int,
 
     t_check = time.monotonic()
     numbers = check.compare(config["codec"], frames, window, run.keys,
-                            desktop, seed)
+                            desktop, seed, check.sampling(config))
     numbers["never_arrived"] = len(never)
     if limits is None:
         limits = runner.limits_for(cell["name"])

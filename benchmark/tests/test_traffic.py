@@ -111,3 +111,14 @@ def test_byte_counts_at_1920x1088():
     assert jpeg.bytes_needed(1920, 1088, intra=False, dirty_rows=68,
                              payload_bytes=200_000) \
         == 2 * frame + 200_000 == 12_733_760
+    # 4:4:4: the reference and the reconstruction at 3 bytes a pixel
+    assert h264.bytes_needed(1920, 1088, intra=False, dirty_rows=68,
+                             payload_bytes=100_000, chroma="444") \
+        == 2 * frame + 2 * frame + 100_000 == 25_167_520
+    assert h264.bytes_needed(1920, 1088, intra=True, dirty_rows=0,
+                             payload_bytes=100_000, chroma="444") \
+        == 2 * frame + frame + 100_000 == 18_900_640
+    # JPEG keeps no reference: its count is the same at either sampling
+    assert jpeg.bytes_needed(1920, 1088, intra=False, dirty_rows=68,
+                             payload_bytes=200_000, chroma="444") \
+        == 12_733_760

@@ -11,9 +11,15 @@ BENCH = Path(__file__).resolve().parent.parent
 WIDTH, HEIGHT = 256, 128
 
 
-def config(name: str) -> dict:
+def config(name: str, chroma: str = "420") -> dict:
+    """A configuration at the tiny geometry; at ``chroma="444"`` its
+    4:4:4 variant: the program's ``fullcolor`` flag on, and the sampling
+    the check holds the stream to."""
     c = json.loads((BENCH / "configs" / f"{name}.json").read_text())
     c["flags"].update(initial_width=WIDTH, initial_height=HEIGHT)
+    if chroma == "444":
+        c["flags"]["fullcolor"] = True
+        c["chroma"] = "444"
     c["coded"] = [WIDTH, -(-HEIGHT // 64) * 64]
     c["warmup"] = {"min_s": 2, "min_frames": 5, "quiet_s": 2, "max_s": 600}
     c["trace_s"] = 1
