@@ -69,15 +69,26 @@ def h264_buffer_caps(g: "_Grid", fullcolor: bool = False
                      ) -> tuple[int, int, int]:
     """(e_cap, w_cap, out_cap) for a grid — shared by the single-seat
     session and the seat-sharded encoder so the sizing policy cannot
-    diverge. out_cap is the one array that crosses the host link every
-    frame, sized for realistic intra frames (~1.5 bits/px); overflow
-    grows it (and forces a clean refresh). 4:4:4 carries 3 luma-style
-    components (~1.5x the slot/bit budget of 4:2:0)."""
+    diverge. w_cap is words per MB row, out_cap bytes per frame: the
+    stream buffer, fetched up to the bytes used (engine/readback.py).
+    Overflow doubles both (and forces a clean refresh), so an undersized
+    buffer costs a recompile and an IDR. 4:2:0: sized for realistic
+    intra frames (~1.5 bits/px).
+
+    4:4:4 (fullcolor) is sized for what a 4:4:4 frame carries, which is
+    about 2.4x a 4:2:0 one under full motion, not 1.5x: out_cap one byte
+    a pixel (a third of the raw samples), w_cap 288 words a macroblock
+    (1.5x a row's raw samples). At 1920x1088 that is 2,088,960 B a frame
+    and 34,560 words a row. The benchmark's full-motion cell at CRF 25
+    (h264-1080p60-444.motion; one v5e, 21 windows and their warm-up, 36
+    IDRs and 484 motion P frames) peaked at a P frame of 1,245,417 B
+    (IDRs 322,118 B) and a row of 21,190 B (5,298 words): margins 1.68x
+    and 6.5x at first build."""
     if fullcolor:
         from ..ops.h264_planes444 import P_SLOTS_MB_444, SLOTS_MB_444
         e_cap = 9 + g.mb_w * max(SLOTS_MB_444, P_SLOTS_MB_444) + 2
         w_cap = max(3072, g.mb_w * 1152 // 4)
-        out_cap = max(288 * 1024, g.width * g.height // 4)
+        out_cap = max(288 * 1024, g.width * g.height)
     else:
         e_cap = 9 + g.mb_w * max(SLOTS_MB, P_SLOTS_MB) + 2
         w_cap = max(2048, g.mb_w * 768 // 4)
@@ -748,8 +759,14 @@ class H264EncoderSession:
                             out["data"], int(starts[last] + lens[last]),
                             tl=tl, lane=lane)
         _tracer.record_span(tl, "encode.readback", rb_t0, lane=lane)
+        if tl is not None and not overflowed and not idle:
+            # how full the stream buffer ran: against the capacity the
+            # frame was ENCODED with (its cap_gen), not today's
+            _tracer.count(tl, "stream.bytes", int(lens.sum()))
+            _tracer.count(tl, "stream.cap",
+                          self._out_cap >> (self._cap_gen - out["cap_gen"]))
         if overflowed:
-            self._handle_overflow(out)
+            self._handle_overflow(out, tl, lane)
             return None
         if idle:
             return None               # idle frame: fetched nothing at all
@@ -832,18 +849,19 @@ class H264EncoderSession:
             seat_index=self.settings.seat_index,
             display_id=self.settings.display_id)
 
-    def _handle_overflow(self, out: dict[str, Any]) -> None:
+    def _handle_overflow(self, out: dict[str, Any], tl, lane) -> None:
         # grow once per episode: pipelined frames encoded with the old
         # caps also report overflow but must not re-double/re-jit
         if out["cap_gen"] == self._cap_gen:
             logger.warning("h264 overflow at frame %d; growing buffers",
                            out["frame_id"])
-            self._w_cap *= 2
-            self._out_cap *= 2
-            # steps BEFORE gen (see encode()'s read order)
-            self._i_step = self._build_step("i")
-            self._p_step = self._build_step("p")
-            self._cap_gen += 1
+            with _tracer.span("encode.regrow", tl, lane=lane):
+                self._w_cap *= 2
+                self._out_cap *= 2
+                # steps BEFORE gen (see encode()'s read order)
+                self._i_step = self._build_step("i")
+                self._p_step = self._build_step("p")
+                self._cap_gen += 1
         with self._drop_lock:
             self._force_after_drop = True
 

@@ -50,6 +50,11 @@ from .relay import VideoRelay
 logger = logging.getLogger("selkies_tpu.server.ws")
 
 ACK_STALL_S = 4.0
+#: a client that looks ACK-stalled is looked at again this much later:
+#: a stall of this process (a blocking call, a collection) leaves its
+#: ACKs unread and its frames unsent, and the loop needs a moment to
+#: catch up before the client's silence counts against it
+ACK_RECHECK_S = 1.0
 RECONNECT_DEBOUNCE_S = 0.5
 CONTROL_SEND_TIMEOUT_S = 2.0  # reference 2 s control bound (selkies.py:79-101)
 #: backpressure logging: one INFO line per window, and windows that
@@ -1888,26 +1893,36 @@ class WebSocketsService(BaseStreamingService):
         pass  # cursor streaming lands with the cursor monitor
 
     # ----------------------------------------------------------------- stats
+    async def _check_ack_stalls(self) -> None:
+        """ACK stall forces backpressure (reference 4 s rule): a client
+        with a frame unacknowledged and no ACK for ``ACK_STALL_S`` is
+        paused, if it is still so ``ACK_RECHECK_S`` later."""
+        def stalled(c) -> bool:
+            return (c.video_active and not c.paused
+                    and c.last_sent_id != c.last_ack_id
+                    and c.last_ack_time < time.monotonic() - ACK_STALL_S)
+
+        if not any(stalled(c) for c in self.clients.values()):
+            return
+        await asyncio.sleep(ACK_RECHECK_S)
+        for c in list(self.clients.values()):
+            if stalled(c):
+                c.paused = True
+                self._apply_pipeline_clamp()
+                metrics.inc_counter("selkies_backpressure_events_total")
+                if c.qoe is not None:
+                    c.qoe.note_stall()
+                    c.qoe.backpressure_begin(time.monotonic())
+                _health.engine.recorder.record(
+                    "ack_stall", client=c.id, display=c.display,
+                    last_sent=c.last_sent_id, last_ack=c.last_ack_id)
+
     async def _stats_loop(self) -> None:
         """Periodic per-client system stats (reference selkies.py:4586-4722)."""
         import psutil
         while self._running:
             await asyncio.sleep(self.settings.stats_interval_s)
-            stalled = time.monotonic() - ACK_STALL_S
-            for c in list(self.clients.values()):
-                # ACK stall forces backpressure (reference 4 s rule)
-                if c.video_active and not c.paused \
-                        and c.last_sent_id != c.last_ack_id \
-                        and c.last_ack_time < stalled:
-                    c.paused = True
-                    self._apply_pipeline_clamp()
-                    metrics.inc_counter("selkies_backpressure_events_total")
-                    if c.qoe is not None:
-                        c.qoe.note_stall()
-                        c.qoe.backpressure_begin(time.monotonic())
-                    _health.engine.recorder.record(
-                        "ack_stall", client=c.id, display=c.display,
-                        last_sent=c.last_sent_id, last_ack=c.last_ack_id)
+            await self._check_ack_stalls()
             # SLO event feed (obs.slo): one fps + one qoe good/bad event
             # per active session per tick. g2g events arrive per frame
             # from _h_frame_timing; these two close the objective set.

@@ -239,3 +239,61 @@ def test_cbp444_table_matches_libavcodec_derivation():
     from selkies_tpu.codecs import h264_tables as T
     derived = derive_cbp444.derive()
     assert np.array_equal(derived, T.CBP444_INTER_CBP2CODE)
+
+
+# ---------------------------------------------------------------------------
+# 5. the fullcolor deployment's session (benchmark configuration
+#    h264-1080p60-444) on a moving scene
+# ---------------------------------------------------------------------------
+
+#: largest per-plane MSE against the source a CRF-25 frame may show here
+#: (sound: 1-18; the same scene at CRF 37 reads 85-240)
+CRF25_PLANE_MSE = 40.0
+
+
+def _ycbcr601_full(rgb):
+    """Independent BT.601 full-range YCbCr of an (H, W, 3) uint8 frame."""
+    r, g, b = (rgb[..., i].astype(np.float64) for i in range(3))
+    return (0.299 * r + 0.587 * g + 0.114 * b,
+            128 - 0.168736 * r - 0.331264 * g + 0.5 * b,
+            128 + 0.5 * r - 0.418688 * g - 0.081312 * b)
+
+
+@needs_av
+def test_fullcolor_session_decodes_to_its_reference_and_the_source():
+    """The configuration's flags (one stream per display, the damage-band
+    P path, motion search 24x8, CRF 25, fullcolor) through
+    H264EncoderSession on a panning scene, an IDR then P frames: each
+    frame decodes under libavcodec at 4:4:4 to exactly the session's own
+    reference planes (no encoder/decoder drift), and within the CRF-25
+    noise of the source."""
+    from selkies_tpu.engine.h264_encoder import H264EncoderSession
+    from selkies_tpu.engine.types import CaptureSettings
+    w, h = 96, 64
+    sess = H264EncoderSession(CaptureSettings(
+        capture_width=w, capture_height=h, single_stream=True,
+        fullcolor=True, video_crf=25, h264_motion_vrange=24,
+        h264_motion_hrange=8, h264_partial_encode=True,
+        use_damage_gating=True))
+    rng = np.random.default_rng(7)
+    tex = np.repeat(np.repeat(rng.integers(
+        0, 256, ((h + 64) // 4, (w + 64) // 4, 3), dtype=np.uint8), 4, 0),
+        4, 1)
+    dec = avshim.H264Session()
+    try:
+        for t in range(5):
+            frame = tex[3 * t:3 * t + h, 2 * t:2 * t + w].copy()
+            chunks = sess.finalize(sess.encode(jnp.asarray(frame),
+                                               force=(t == 0)))
+            assert [c.is_idr for c in chunks] == [t == 0]
+            got = dec.decode(chunks[0].payload) or dec.flush()
+            assert got is not None, f"frame {t} did not decode"
+            refs = (sess._ref_y, sess._ref_u, sess._ref_v)
+            for ci, (plane, ref, src) in enumerate(
+                    zip(got, refs, _ycbcr601_full(frame))):
+                assert plane.shape == (h, w), (t, ci, plane.shape)
+                assert np.array_equal(plane, np.asarray(ref)[:h]), (t, ci)
+                mse = float(np.mean((plane - src) ** 2))
+                assert mse <= CRF25_PLANE_MSE, (t, ci, mse)
+    finally:
+        dec.close()

@@ -117,7 +117,9 @@ def _full_dirty_frames(n=3):
     {"h264_motion_vrange": 8, "h264_motion_hrange": 2},   # motion bands
     {"fullcolor": True},                                  # 4:4:4
     {"single_stream": True},                              # one stream
-], ids=["zeromv", "motion", "444", "single"])
+    {"fullcolor": True, "h264_motion_vrange": 8,          # 4:4:4 motion
+     "h264_motion_hrange": 2},
+], ids=["zeromv", "motion", "444", "single", "444-motion"])
 def test_partial_full_dirty_byte_identical_to_stock(cfg):
     frames = _full_dirty_frames()
     kw = dict(BASE, **cfg)

@@ -1441,3 +1441,50 @@ async def test_prewarm_disabled_by_setting(client_factory):
     body = await (await c.get("/api/prewarm")).json()
     assert body["enabled"] is False and body["worker"] is None
     assert server.ladder.gate is None
+
+
+# ------------------------------------------------- ACK stall vs a host stall
+def _ack_waiting_client(svc, cid=1):
+    """A video client whose last frame has gone unacknowledged for longer
+    than the ACK stall rule allows."""
+    import time
+    import types
+
+    from selkies_tpu.server import ws_service
+    c = types.SimpleNamespace(
+        id=cid, display=":0", video_active=True, paused=False, qoe=None,
+        last_sent_id=69, last_ack_id=68,
+        last_ack_time=time.monotonic() - ws_service.ACK_STALL_S - 3)
+    svc.clients[cid] = c
+    return c
+
+
+async def test_ack_stall_pauses_a_client_that_stays_silent(monkeypatch):
+    from selkies_tpu.server import ws_service
+    monkeypatch.setattr(ws_service, "ACK_RECHECK_S", 0.05)
+    _, svc, _, _ = make_app()
+    c = _ack_waiting_client(svc)
+    await svc._check_ack_stalls()
+    assert c.paused
+
+
+async def test_ack_read_after_a_stall_of_the_server_does_not_pause(
+        monkeypatch):
+    """The server process stalled (its loop blocked) with the client's
+    ACK unread: the ACK that the loop reads as it catches up, within the
+    recheck, keeps the client streaming."""
+    import time
+
+    from selkies_tpu.server import ws_service
+    monkeypatch.setattr(ws_service, "ACK_RECHECK_S", 0.2)
+    _, svc, _, _ = make_app()
+    c = _ack_waiting_client(svc)
+
+    async def ack_arrives():
+        await asyncio.sleep(0.02)
+        c.last_ack_id, c.last_ack_time = 69, time.monotonic()
+
+    task = asyncio.create_task(ack_arrives())
+    await svc._check_ack_stalls()
+    await task
+    assert not c.paused
